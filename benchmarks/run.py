@@ -71,6 +71,8 @@ def main() -> None:
     unknown = [k for k in selected if k not in _MODULES]
     if unknown:
         sys.exit(f"unknown benchmarks {unknown}; choose from {list(BENCHES)}")
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sc = scale()
     print(f"# repro benchmarks  scale={sc}")
     print("name,us_per_call,derived")

@@ -49,7 +49,7 @@ import numpy as np
 
 from ..launch.mesh import auto_pop_shards, make_pop_mesh
 from ..obs import telemetry as _obs
-from ..sharding.rules import get_shard_map, member_spec, segment_member_spec
+from ..sharding.rules import member_spec, segment_member_spec
 from .archspec import (ArchSpec, CompiledSpec, engine_group_key,
                        resolve_spec)
 from .lru import LRUCache
@@ -420,7 +420,7 @@ def make_fused_fleet_runner(workload: Workload, specs: list[ArchSpec],
         ys_specs = (segment_member_spec(4), segment_member_spec(2),
                     segment_member_spec(0))
         best_specs = PopulationBest(edp=_P(), f=_P(), orders=_P())
-        return get_shard_map()(
+        return jax.shard_map(
             sharded, mesh=mesh,
             in_specs=(member_spec(theta.ndim - 1),
                       member_spec(orders.ndim - 1), sp_specs),

@@ -431,7 +431,10 @@ def population_best_init(f: jnp.ndarray,
                          orders: jnp.ndarray) -> PopulationBest:
     """Empty best-tracking state shaped like one population candidate
     (+inf EDP, so the first update always takes)."""
-    return PopulationBest(edp=jnp.full(f.shape[0], jnp.inf, dtype=f.dtype),
+    # Every leaf is derived from `f`/`orders` so that under `shard_map`
+    # the state is varying over the member axis, as the scan carry
+    # that updates it is.
+    return PopulationBest(edp=jnp.full_like(f, jnp.inf, shape=f.shape[:1]),
                           f=jnp.zeros_like(f),
                           orders=jnp.zeros_like(orders))
 

@@ -100,8 +100,7 @@ from .rounding import (round_all, round_population, rounding_tables,
                        _round_population_core)
 from ..launch.mesh import auto_pop_shards, make_pop_mesh
 from ..obs import telemetry as _obs
-from ..sharding.rules import (POP_AXIS, get_shard_map, member_spec,
-                              segment_member_spec)
+from ..sharding.rules import POP_AXIS, member_spec, segment_member_spec
 
 # The default target's compiled spec, hoisted to a module constant so
 # the Gemmini-default paths of `build_f` / `theta_from_mappings` touch
@@ -611,7 +610,7 @@ def make_fused_runner(workload: Workload, cfg: SearchConfig):
                         segment_member_spec(2),   # orders  (S, P, L, nl)
                         segment_member_spec(0))   # edp     (S, P)
             best_specs = PopulationBest(edp=_P(), f=_P(), orders=_P())
-            return get_shard_map()(
+            return jax.shard_map(
                 sharded, mesh=mesh,
                 in_specs=(member_spec(theta.ndim - 1),
                           member_spec(orders.ndim - 1)),
@@ -658,7 +657,9 @@ def _cd_orderings(e: jnp.ndarray, lat: jnp.ndarray,
             layer_step, (choice, e_tot, l_tot), (jnp.arange(L), e, lat))
         return choice, ()
 
-    choice0 = jnp.zeros(L, dtype=jnp.int32)
+    # Built from `e` (not `jnp.zeros(L)`) so that under `shard_map`
+    # the carry is varying over the "pop" axis like the scan's output.
+    choice0 = jnp.zeros_like(e[:, 0], dtype=jnp.int32)
     choice, _ = jax.lax.scan(one_pass, choice0, None, length=n_passes)
     return choice
 

@@ -1,7 +1,6 @@
-"""Jit'd wrapper with GQA head handling + interpret fallback."""
+"""Jit'd wrapper with GQA head handling."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .flash_attention import flash_attention
@@ -10,10 +9,9 @@ from .ref import attention_ref  # noqa: F401  (public kernel surface)
 
 def gqa_flash_attention(q, k, v, *, causal: bool = True,
                         bq: int = 512, bkv: int = 512,
-                        interpret: bool | None = None):
-    """q: (B, Hq, S, D); k, v: (B, Hkv, S, D) with Hq % Hkv == 0."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                        interpret: bool = False):
+    """q: (B, Hq, S, D); k, v: (B, Hkv, S, D) with Hq % Hkv == 0.
+    `interpret=True` runs the kernel in the Pallas interpreter."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     group = hq // hkv

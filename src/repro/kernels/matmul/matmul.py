@@ -19,6 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...core.tpu_model import scoped_vmem_limit
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, n_k: int):
@@ -51,6 +54,11 @@ def matmul(x: jax.Array, y: jax.Array, *, bm: int = 256, bk: int = 512,
         (m, k, n, bm, bk, bn)
     n_k = k // bk
     kernel = functools.partial(_matmul_kernel, n_k=n_k)
+    # Double-buffered x, y and output tiles plus the f32 accumulator
+    # (`core.tpu_model.vmem_footprint`, which the tuner bounds).
+    itemsize = jnp.dtype(x.dtype).itemsize
+    footprint = (2 * (bm * bk + bk * bn + bm * bn) * itemsize
+                 + 4 * bm * bn)
     return pl.pallas_call(
         kernel,
         grid=(m // bm, n // bn, n_k),
@@ -60,12 +68,8 @@ def matmul(x: jax.Array, y: jax.Array, *, bm: int = 256, bk: int = 512,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        scratch_shapes=[_vmem_scratch((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=scoped_vmem_limit(footprint)),
         interpret=interpret,
     )(x, y)
-
-
-def _vmem_scratch(shape, dtype):
-    """f32 accumulator tile resident in VMEM."""
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)

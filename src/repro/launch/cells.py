@@ -262,11 +262,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     model = build_model(cfg)
     res.n_params = float(cfg.n_params())
 
-    # jax.sharding.set_mesh only exists on newer jax (>= 0.5); older
-    # versions use the Mesh itself as the ambient-mesh context manager.
-    # Shardings are passed explicitly below either way.
-    _mesh_ctx = getattr(jax.sharding, "set_mesh", lambda m: m)
-    with _mesh_ctx(mesh):
+    with jax.sharding.set_mesh(mesh):
         # Trace/lower/compile timed as engine.build-family telemetry
         # spans (visible when a tracer is enabled) on the shared clock.
         tracer = _obs.get_tracer()

@@ -1,8 +1,5 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/repro_jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "4")
 
 # --- everything below may import jax ---------------------------------------
 """Multi-pod dry-run driver.
@@ -66,6 +63,8 @@ def main() -> None:
     ap.add_argument("--subprocess", action="store_true")
     ap.add_argument("--out", default="artifacts/dryrun")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out_dir = pathlib.Path(args.out)
 
     if args.all:

@@ -1,8 +1,5 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/repro_jax_cache")
-os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "4")
 
 # ---------------------------------------------------------------------------
 """Sec. Perf hillclimbing driver: re-lower a dry-run cell under a named
@@ -101,6 +98,8 @@ def main() -> None:
                     choices=sorted(VARIANTS))
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(args.arch, args.shape, args.variant, args.multi_pod)
 
 

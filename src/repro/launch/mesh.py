@@ -15,19 +15,11 @@ import jax
 import numpy as np
 
 
-def _auto_axis_kwargs(n):
-    """`axis_types` only exists on newer jax (>= 0.5); Auto is already
-    the default there, so on older versions we simply omit the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -36,7 +28,8 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     assert n % model == 0
     return jax.make_mesh((1, n // model, model),
-                         ("pod", "data", "model"), **_auto_axis_kwargs(3))
+                         ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 
 @functools.lru_cache(maxsize=None)

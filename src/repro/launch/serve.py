@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from ..runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)

@@ -7,6 +7,7 @@ serving stack (`telemetry`), plus the npz-backed search-history store
 from .telemetry import (  # noqa: F401
     MetricsRegistry,
     Tracer,
+    compile_spans,
     default_clock,
     get_metrics,
     get_tracer,

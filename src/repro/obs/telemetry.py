@@ -24,6 +24,7 @@ exposition format.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -166,11 +167,24 @@ class Tracer:
             return -1
         if parent_id is None:
             parent_id = self.current_span_id()
+        return self._insert(name, parent_id, self._clock(), None, attrs)
+
+    def add_span(self, name: str, duration_s: float, **attrs) -> None:
+        """Record a finished span of `duration_s` that ends now, under
+        this thread's innermost open span (for work timed elsewhere)."""
+        if not self.enabled:
+            return
         now = self._clock()
+        self._insert(name, self.current_span_id(), now - duration_s, now,
+                     attrs)
+
+    def _insert(self, name: str, parent_id: Optional[int],
+                t_start: float, t_end: Optional[float],
+                attrs: dict) -> int:
         with self._lock:
             sid = self._next_id
             self._next_id += 1
-            self._spans[sid] = Span(sid, parent_id, name, now,
+            self._spans[sid] = Span(sid, parent_id, name, t_start, t_end,
                                     attrs=dict(attrs))
             self._order.append(sid)
             self._evict_locked()
@@ -552,6 +566,33 @@ def profile_build(build: Callable, *, kind: str, cache: str,
     value = build()
     dt = finish_build(token)
     return value, dt
+
+
+# ------------------------------------------------- XLA compile spans
+
+# The duration JAX reports for each XLA backend compile; a persistent
+# compilation-cache hit is timed under it too (the lookup replaces the
+# compile).
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def compile_spans():
+    """Within the block, record every XLA backend compile of the process
+    as an ``engine.compile`` span on the global tracer (attr
+    ``fun_name``), under the compiling thread's open span."""
+    import jax.monitoring
+
+    def listener(event: str, duration_s: float, **kwargs) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            get_tracer().add_span("engine.compile", duration_s,
+                                  fun_name=kwargs.get("fun_name", ""))
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
 
 
 # ------------------------------------------------------------- globals

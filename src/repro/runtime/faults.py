@@ -7,18 +7,22 @@ treated every `ValueError` as transient, so a deterministic bad input
 burned the whole restart budget replaying a failure that could never
 succeed.  This module is the single classification both drivers use:
 
-* **transient** — device/runtime faults (preemption, OOM — jax surfaces
-  them as `RuntimeError` subclasses), checkpoint I/O failures
-  (`OSError`) and bad numeric state (`FloatingPointError`).  Worth
-  retrying with exponential backoff, bounded by `RetryPolicy.max_retries`.
+* **transient** — device/runtime faults (preemption, a lost device —
+  jax surfaces them as `RuntimeError` subclasses), checkpoint I/O
+  failures (`OSError`) and bad numeric state (`FloatingPointError`).
+  Worth retrying with exponential backoff, bounded by
+  `RetryPolicy.max_retries`.
 * **poison** — a deterministic input failure: the same fault signature
   (type + message) re-fires after a replay.  `ValueError` starts with
   one retry of grace (it *can* be a transient decode hiccup); a second
   identical failure proves determinism and reclassifies to poison.
   Poison work is quarantined, never retried — one bad request must not
   exhaust a batch's restart budget or take sibling requests down.
-* **fatal** — programming errors (`AttributeError`, `TypeError`, ...)
-  and anything unrecognized: propagate immediately, loudly.
+* **fatal** — programming errors (`AttributeError`, `TypeError`, ...),
+  XLA errors that the same program on the same inputs raises again (a
+  compile refusal, device out-of-memory, a bad or donated argument —
+  retrying only recompiles and re-fails), and anything unrecognized:
+  propagate immediately, loudly.
 
 Deadlines (`Deadline`) and backoff (`backoff_s`) take an *injected*
 clock so engine-path code never reads the wall clock directly (rule
@@ -27,6 +31,8 @@ ND202); the serving layer defaults the clock at its boundary.
 from __future__ import annotations
 
 import dataclasses
+
+import jax
 
 # Fault classes ------------------------------------------------------------
 
@@ -41,6 +47,24 @@ TRANSIENT_TYPES = (RuntimeError, OSError, FloatingPointError)
 # Deterministic-input suspects: retried once, then poison on an
 # identical re-failure (see module doc).
 POISON_SUSPECT_TYPES = (ValueError,)
+
+# Status codes of `jax.errors.JaxRuntimeError` (its message starts with
+# one) that no retry can clear: the compiler refused the program, the
+# device ran out of memory, or an argument is invalid.
+DETERMINISTIC_XLA_STATUSES = frozenset({
+    "INVALID_ARGUMENT", "RESOURCE_EXHAUSTED", "FAILED_PRECONDITION",
+    "OUT_OF_RANGE", "UNIMPLEMENTED"})
+
+
+def is_deterministic_device_fault(exc: BaseException) -> bool:
+    """A device/runtime `RuntimeError` that replaying cannot fix: an
+    XLA error with a deterministic status, or a read of an array whose
+    buffer was deleted or donated to an earlier call."""
+    if isinstance(exc, jax.errors.JaxRuntimeError):
+        status = str(exc).split(":", 1)[0].strip()
+        return status in DETERMINISTIC_XLA_STATUSES
+    return (isinstance(exc, RuntimeError)
+            and str(exc).startswith("Array has been deleted"))
 
 
 class ShardLossFault(RuntimeError):
@@ -70,7 +94,8 @@ def classify(exc: BaseException, seen_before: bool = False) -> str:
     if isinstance(exc, POISON_SUSPECT_TYPES) and not isinstance(
             exc, TRANSIENT_TYPES):
         return POISON if seen_before else TRANSIENT
-    if isinstance(exc, TRANSIENT_TYPES):
+    if isinstance(exc, TRANSIENT_TYPES) \
+            and not is_deterministic_device_fault(exc):
         return TRANSIENT
     return FATAL
 

@@ -86,16 +86,6 @@ def segment_member_spec(extra_dims: int = 0) -> P:
     return P(None, POP_AXIS, *([None] * extra_dims))
 
 
-def get_shard_map():
-    """`shard_map` across jax versions: `jax.experimental.shard_map`
-    on 0.4.x, promoted to `jax.shard_map` later."""
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:             # pragma: no cover - newer jax
-        from jax import shard_map
-    return shard_map
-
-
 # Activation constraint specs.  Attention uses Ulysses-style sequence
 # parallelism over "model" (all-to-all between D-sharded projections and
 # S-sharded attention core) — uniform across head counts (28-head qwen2,

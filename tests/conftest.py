@@ -1,22 +1,6 @@
 """Shared fixtures.  NOTE: XLA_FLAGS / device-count tricks are strictly
 confined to launch/dryrun.py and subprocess-based tests — the main test
 process must see the real single CPU device."""
-import importlib.util
-import sys
-from pathlib import Path
-
-try:  # pragma: no cover - exercised only where hypothesis is missing
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    # Install the seeded-sampling fallback (tests/_hypothesis_fallback.py)
-    # so property tests still run without `pip install -e .[test]`.
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis", Path(__file__).with_name("_hypothesis_fallback.py"))
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
-
 import numpy as np
 import pytest
 

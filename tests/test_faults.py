@@ -24,6 +24,30 @@ def test_classify_valueerror_poisons_only_on_refailure():
     assert faults.classify(exc, seen_before=True) == faults.POISON
 
 
+@pytest.mark.parametrize("message,expected", [
+    ("RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem",
+     faults.FATAL),
+    ("INVALID_ARGUMENT: Buffer has been deleted or donated",
+     faults.FATAL),
+    ("UNAVAILABLE: TPU device lost", faults.TRANSIENT),
+])
+def test_classify_xla_runtime_errors_by_status(message, expected):
+    """A compile refusal, an OOM or a bad argument fails again on a
+    replay of the same program, so only other statuses are retried."""
+    import jax
+    assert faults.classify(jax.errors.JaxRuntimeError(message)) \
+        == expected
+
+
+def test_classify_deleted_array_is_fatal():
+    import jax.numpy as jnp
+    x = jnp.ones(3)
+    x.delete()
+    with pytest.raises(RuntimeError) as info:
+        x + 1
+    assert faults.classify(info.value) == faults.FATAL
+
+
 def test_classify_fatal():
     for exc in (TypeError("t"), AttributeError("a"), KeyError("k")):
         assert faults.classify(exc) == faults.FATAL

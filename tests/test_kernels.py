@@ -61,7 +61,7 @@ def test_tuned_matmul_wrapper():
     from repro.kernels.matmul.ops import tuned_matmul
     x = jax.random.normal(jax.random.PRNGKey(0), (256, 768))
     y = jax.random.normal(jax.random.PRNGKey(1), (768, 512))
-    out = tuned_matmul(x, y)
+    out = tuned_matmul(x, y, interpret=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(matmul_ref(x, y)), rtol=1e-4,
                                atol=1e-4)

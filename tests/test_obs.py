@@ -253,6 +253,48 @@ def test_engine_build_span_and_cache_build_time():
     assert any(lbl.startswith("fused:") for lbl in st["build_seconds"])
 
 
+def test_compile_spans_record_xla_compiles_under_the_open_span():
+    """Inside `compile_spans()` each XLA compile becomes a finished
+    engine.compile span parented on the compiling thread's span;
+    outside it nothing is recorded."""
+    import jax
+    import jax.numpy as jnp
+    tr = obs.Tracer()
+    old = obs.set_tracer(tr)
+    try:
+        with obs.compile_spans():
+            with tr.span("search.fused_dispatch"):
+                jax.jit(lambda x: x * 3.0 + 1.0)(jnp.arange(5.0))
+        jax.jit(lambda x: x - 2.0)(jnp.arange(5.0))
+    finally:
+        obs.set_tracer(old)
+    parent = tr.spans_named("search.fused_dispatch")[0]
+    compiles = tr.spans_named("engine.compile")
+    assert any("lambda" in s.attrs["fun_name"] for s in compiles)
+    assert all(s.parent_id == parent.span_id for s in compiles)
+    assert all(s.t_end is not None and s.duration_s > 0
+               for s in compiles)
+    assert tr.total_s("engine.compile") <= parent.duration_s
+
+
+def test_compile_cache_dir_env_wins_else_fixed_checkout_path(
+        monkeypatch):
+    import jax
+    from repro.runtime import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.REPO_CACHE_DIR)
+        assert path.endswith("/.jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 # ---------------------------------------------------------------------------
 # Search-history recorder
 # ---------------------------------------------------------------------------

@@ -25,20 +25,15 @@ _SCRIPT = textwrap.dedent("""
     from repro.sharding.rules import sanitize_spec
 
     assert len(jax.devices()) == 8
-    # axis_types / set_mesh only exist on newer jax (>= 0.5); on older
-    # versions Auto is the default and the Mesh is the ambient context.
-    _mesh_kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        _mesh_kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 3
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **_mesh_kw)
-    _mesh_ctx = getattr(jax.sharding, "set_mesh", lambda m: m)
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
     cfg = get_config("qwen3_0_6b", reduced=True)
     import dataclasses
     cfg = dataclasses.replace(cfg, compute_dtype="float32")
     model = build_model(cfg)
 
-    with _mesh_ctx(mesh):
+    with jax.sharding.set_mesh(mesh):
         params, specs = model.init(jax.random.PRNGKey(0))
         names = set(mesh.axis_names)
         shardings = jax.tree.map(
