@@ -1,7 +1,7 @@
 """Telemetry-spine gates: instrumentation must be free when off,
 invisible to the numbers when on, and complete when served.
 
-Four gates (benchmarks.run exits non-zero on failure):
+Three gates (benchmarks.run exits non-zero on failure):
 
 * **parity** — a fused search with an enabled tracer reports the
   bit-identical (best EDP, sample count, history) result of the same
@@ -17,10 +17,6 @@ Four gates (benchmarks.run exits non-zero on failure):
   service yields a complete rooted lifecycle trace: a ``request`` root
   whose events start at ``submitted`` and end at ``drain``, with a
   ``queue_wait`` child and one ``segment`` child per rounding segment.
-* **history** — the search-history recorder captured one row per
-  segment whose best-EDP column matches the request's streamed event
-  EDPs exactly (the learned-seeding dataset contract), and the store
-  round-trips through its npz form.
 
 Writes ``bench_results/obs_metrics.json``.
 """
@@ -30,10 +26,9 @@ from repro.api import SearchRequest
 from repro.core.problem import Layer, Workload
 from repro.core.search import SearchConfig, dosa_search
 from repro.obs import telemetry as obs
-from repro.obs.history import HistoryRecorder
 from repro.serve.cosearch_service import CoSearchService, ServiceConfig
 
-from .common import OUTPUT_DIR, Row, Timer, save_json
+from .common import Row, Timer, save_json
 
 POPULATION = 4
 WL = Workload(layers=(Layer.matmul(32, 32, 32, name="m"),), name="obs_wl")
@@ -102,7 +97,7 @@ def run(scale: str = "quick") -> list[Row]:
         f"({n_points} spans x {per_span_s*1e6:.3f}us over "
         f"{t_off.seconds:.3f}s) exceeds the {NOOP_GATE:.0%} gate")
 
-    # ---- gates 3+4: served lifecycle trace + history rows
+    # ---- gate 3: served lifecycle trace
     svc = CoSearchService(ServiceConfig(bucket_workloads=False))
     req = SearchRequest(workload=WL, config=cfg)
     rid = svc.submit(req)
@@ -121,18 +116,6 @@ def run(scale: str = "quick") -> list[Row]:
     assert "queue_wait" in kids and len(segs) == n_segments, (
         f"span tree has {len(segs)} segment children, expected "
         f"{n_segments} (children: {kids})")
-
-    events = svc.events(rid)
-    rows = svc.history.rows(rid)
-    assert [r.segment for r in rows] == [e.segment for e in events] \
-        and [r.best_edp for r in rows] == [e.best_edp for e in events], (
-        "history rows disagree with the request's event stream")
-    assert rows[-1].best_edp == out.result.best_edp
-    hist_path = OUTPUT_DIR / "obs_history.npz"
-    OUTPUT_DIR.mkdir(parents=True, exist_ok=True)
-    n_saved = svc.history.save(hist_path)
-    reloaded = HistoryRecorder.load(hist_path)
-    assert len(reloaded) == n_saved == len(rows)
 
     metrics_text = svc.metrics_text()
     assert "serve_requests_completed_total" in metrics_text
@@ -154,9 +137,7 @@ def run(scale: str = "quick") -> list[Row]:
         "span_names": span_names,
         "served": {"n_segments": n_segments,
                    "segment_children": len(segs),
-                   "lifecycle_events": ev_names,
-                   "history_rows": len(rows),
-                   "history_npz_rows": n_saved},
+                   "lifecycle_events": ev_names},
         "service_metrics": svc.metrics.snapshot(),
     })
     return [
@@ -168,7 +149,4 @@ def run(scale: str = "quick") -> list[Row]:
         Row("obs_served_trace", 0.0,
             f"segments={len(segs)}/{n_segments} "
             f"events={len(ev_names)} drain=ok"),
-        Row("obs_history", 0.0,
-            f"rows={len(rows)} npz={n_saved} "
-            f"edp_match=exact"),
     ]

@@ -551,27 +551,37 @@ def make_fused_runner(workload: Workload, cfg: SearchConfig):
         combos = jnp.asarray(cspec.combos)
         reselect = cfg.ordering_mode in ("iterative", "softmax")
 
+        # The four phases carry named scopes: every device op's op_name
+        # metadata starts with the phase that owns it, so a profile
+        # splits the program's device time by phase.
         def segment(theta, orders, best, n_steps: int):
-            theta = _adam_scan(pop_grad, cfg.lr, theta, (orders,), n_steps)
-            f_cont = jax.vmap(
-                lambda th: build_f(th, dims, free_mask_j))(theta)
-            f_round, theta = _round_population_core(cspec, tables, f_cont,
-                                                    pe_cap)
+            with jax.named_scope("gd"):
+                theta = _adam_scan(pop_grad, cfg.lr, theta, (orders,),
+                                   n_steps)
+            with jax.named_scope("round"):
+                f_cont = jax.vmap(
+                    lambda th: build_f(th, dims, free_mask_j))(theta)
+                f_round, theta = _round_population_core(cspec, tables,
+                                                        f_cont, pe_cap)
             if reselect:
-                if hw_fixed is not None:
-                    hws = jax.tree_util.tree_map(
-                        lambda x: jnp.broadcast_to(
-                            x, theta.shape[:1] + jnp.shape(x)), hw_fixed)
-                else:
-                    hws = infer_hw_population_spec(cspec, f_round, strides)
-                e, lat = layer_el_all_orderings_population_spec(
-                    cspec, f_round, strides, hws)
-                rep = repeats[None, :, None]
-                choice = jax.vmap(_cd_orderings)(e * rep, lat * rep)
-                orders = combos[choice]                # (P, L, n_levels)
-            edp = population_edp_spec(cspec, f_round, orders, strides,
-                                      repeats, hw=hw_fixed)
-            best = population_best_update(best, edp, f_round, orders)
+                with jax.named_scope("ordering"):
+                    if hw_fixed is not None:
+                        hws = jax.tree_util.tree_map(
+                            lambda x: jnp.broadcast_to(
+                                x, theta.shape[:1] + jnp.shape(x)),
+                            hw_fixed)
+                    else:
+                        hws = infer_hw_population_spec(cspec, f_round,
+                                                       strides)
+                    e, lat = layer_el_all_orderings_population_spec(
+                        cspec, f_round, strides, hws)
+                    rep = repeats[None, :, None]
+                    choice = jax.vmap(_cd_orderings)(e * rep, lat * rep)
+                    orders = combos[choice]            # (P, L, n_levels)
+            with jax.named_scope("best"):
+                edp = population_edp_spec(cspec, f_round, orders, strides,
+                                          repeats, hw=hw_fixed)
+                best = population_best_update(best, edp, f_round, orders)
             return theta, orders, best, (f_round, orders, edp)
 
         def run_all(theta, orders, n_full: int, rem: int, seg_len: int):
@@ -739,12 +749,14 @@ def _oracle_edp(mappings, workload, cfg, cspec: CompiledSpec) -> float:
 class _Recorder:
     """Sample accounting shared by the sequential and batched drivers:
     every differentiable-model step and every oracle evaluation counts
-    as one sample (Sec. 6.3)."""
+    as one sample (Sec. 6.3).  `improved` counts the recorded candidates
+    that set a new best (and so paid for `minimal_hw_for`)."""
 
     def __init__(self, workload: Workload, cfg: SearchConfig,
                  cspec: CompiledSpec):
         self.workload, self.cfg, self.cspec = workload, cfg, cspec
         self.evals = 0
+        self.improved = 0
         if cspec.spec is GEMMINI_SPEC:
             hw0 = GemminiHW(1, 1.0, 1.0)
         else:
@@ -762,6 +774,7 @@ class _Recorder:
         edp = _oracle_edp(mappings, self.workload, cfg, self.cspec)
         self.evals += 1
         if edp < best.best_edp:
+            self.improved += 1
             best.best_edp = edp
             best.best_mappings = [m.copy() for m in mappings]
             hw = minimal_hw_for(self.cspec, mappings,
@@ -1022,9 +1035,11 @@ def _dosa_search_batched(workload: Workload, cfg: SearchConfig,
                     for ms, no in zip(rounded_pop, new_orders):
                         for mp, o in zip(ms, no):
                             mp.order = o
-            with tracer.span("search.oracle", segment=seg):
+            with tracer.span("search.oracle", segment=seg) as sp:
+                improved = rec.improved
                 for ms in rounded_pop[:n_real]:
                     rec.record(ms)
+                sp.set(candidates=n_real, improved=rec.improved - improved)
             # Continue GD from the rounded points, fresh momentum.
             theta = jnp.asarray(
                 theta_from_population(rounded_pop, cspec.free_mask),
@@ -1065,7 +1080,7 @@ def _dosa_search_fused(workload: Workload, cfg: SearchConfig,
     starts = []
     if not device_seeded:
         with _obs.get_tracer().span("search.starts",
-                                    n=cfg.n_start_points):
+                                    n=cfg.n_start_points) as sp:
             rng = np.random.default_rng(cfg.seed)
             best_start_edp = float("inf")
             for _ in range(cfg.n_start_points):
@@ -1073,6 +1088,7 @@ def _dosa_search_fused(workload: Workload, cfg: SearchConfig,
                     workload, cfg, rng, best_start_edp, rec)
                 rec.best.start_edps.append(edp0)
                 starts.append(mappings)
+            sp.set(tries=rec.evals)     # one count per oracle-checked try
 
     seg_lens = _segment_lengths(cfg.steps, cfg.round_every)
     n_full, rem = divmod(cfg.steps, cfg.round_every)
@@ -1126,10 +1142,12 @@ def _dosa_search_fused(workload: Workload, cfg: SearchConfig,
             f_seg = np.asarray(f_seg, dtype=float)  # (S, P, L, 2, nl, 7)
             o_seg = np.asarray(o_seg)               # (S, P, L, n_levels)
         for s, n_steps in enumerate(seg_lens):
-            with tracer.span("search.oracle", segment=s, chunk=lo):
+            with tracer.span("search.oracle", segment=s, chunk=lo) as sp:
                 rec.count(n_steps * n_real)  # one sample per GD step
+                improved = rec.improved
                 for p in range(n_real):
                     rec.record(
                         unstack_mappings(f_seg[s, p], o_seg[s, p]))
+                sp.set(candidates=n_real, improved=rec.improved - improved)
 
     return rec.finish()

@@ -1,8 +1,7 @@
-"""Observability spine: structured spans, metrics, search history.
+"""Observability spine: structured spans and metrics.
 
 One tracer/metrics layer shared by the fused engine, the fleet and the
-serving stack (`telemetry`), plus the npz-backed search-history store
-(`history`) that the learned-seeding ROADMAP item will train on.
+serving stack (`telemetry`).
 """
 from .telemetry import (  # noqa: F401
     MetricsRegistry,
@@ -14,4 +13,3 @@ from .telemetry import (  # noqa: F401
     render_prometheus,
     set_tracer,
 )
-from .history import HistoryRecorder  # noqa: F401

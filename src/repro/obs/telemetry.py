@@ -18,14 +18,12 @@ Design constraints, in order:
   span parenting uses a per-thread stack (plus explicit ``parent_id``
   for request lifecycles that cross scheduler steps).
 
-Spans export as JSONL (one span per line) or as a Chrome-trace /
-Perfetto ``traceEvents`` JSON; metrics render in the Prometheus text
-exposition format.
+Metrics render in the Prometheus text exposition format.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
-import json
 import threading
 import time
 from dataclasses import dataclass, field
@@ -116,8 +114,9 @@ class Tracer:
     ``with tracer.span("engine.build", kind="fused"): ...`` nests via a
     per-thread stack; lifecycles that outlive one call frame use
     ``start_span``/``end_span`` with explicit ``parent_id``.  Bounded:
-    the oldest *finished* root trees are dropped past ``max_spans``
-    (counted in ``dropped``).
+    past ``max_spans`` the span that finished longest ago is dropped
+    (counted in ``dropped``) in amortised O(1); open spans are never
+    dropped.
     """
 
     def __init__(self, clock: Callable[[], float] | None = None,
@@ -125,8 +124,10 @@ class Tracer:
         self.enabled = enabled
         self._clock = clock if clock is not None else default_clock
         self._lock = threading.Lock()
+        # insertion-ordered (ids only grow); finished ids in finish
+        # order, the eviction queue
         self._spans: dict[int, Span] = {}
-        self._order: list[int] = []
+        self._finished: collections.deque[int] = collections.deque()
         self._next_id = 1
         self._tls = threading.local()
         self.max_spans = max_spans
@@ -186,7 +187,8 @@ class Tracer:
             self._next_id += 1
             self._spans[sid] = Span(sid, parent_id, name, t_start, t_end,
                                     attrs=dict(attrs))
-            self._order.append(sid)
+            if t_end is not None:
+                self._finished.append(sid)
             self._evict_locked()
         return sid
 
@@ -198,6 +200,7 @@ class Tracer:
             sp = self._spans.get(span_id)
             if sp is not None and sp.t_end is None:
                 sp.t_end = now
+                self._finished.append(span_id)
                 if attrs:
                     sp.attrs.update(attrs)
 
@@ -225,24 +228,20 @@ class Tracer:
                 sp.attrs.update(attrs)
 
     def _evict_locked(self) -> None:
-        # Drop oldest finished spans past the bound; open spans (live
-        # request roots) are never dropped.
-        while len(self._order) > self.max_spans:
-            for i, sid in enumerate(self._order):
-                sp = self._spans.get(sid)
-                if sp is None or sp.t_end is not None:
-                    del self._order[i]
-                    self._spans.pop(sid, None)
-                    self.dropped += 1
-                    break
-            else:
-                break  # everything still open — refuse to drop
+        # Drop the longest-finished spans past the bound; open spans
+        # (live request roots) are never in the queue.
+        while len(self._spans) > self.max_spans and self._finished:
+            if self._spans.pop(self._finished.popleft(), None) is not None:
+                self.dropped += 1
 
-    # -- queries / export
+    # -- queries
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._spans)
+
     def spans(self) -> list[Span]:
         with self._lock:
-            return [self._spans[s] for s in self._order
-                    if s in self._spans]
+            return list(self._spans.values())
 
     def spans_named(self, name: str) -> list[Span]:
         return [s for s in self.spans() if s.name == name]
@@ -255,56 +254,28 @@ class Tracer:
     def tree(self, root_id: int) -> Optional[dict]:
         """Nested ``{span..., "children": [...]}`` dict rooted at
         ``root_id``, children in start order; None if unknown."""
+        return self.trees([root_id])[0]
+
+    def trees(self, root_ids) -> list[Optional[dict]]:
+        """`tree` of each of `root_ids`, from one pass over the store."""
         with self._lock:
-            if root_id not in self._spans:
-                return None
             kids: dict[int, list[int]] = {}
-            for sid in self._order:
-                sp = self._spans.get(sid)
-                if sp is not None and sp.parent_id is not None:
+            for sid, sp in self._spans.items():
+                if sp.parent_id is not None:
                     kids.setdefault(sp.parent_id, []).append(sid)
 
             def build(sid: int) -> dict:
                 d = self._spans[sid].to_dict()
-                d["children"] = [build(c) for c in kids.get(sid, ())
-                                 if c in self._spans]
+                d["children"] = [build(c) for c in kids.get(sid, ())]
                 return d
 
-            return build(root_id)
-
-    def export_jsonl(self, path) -> int:
-        """One span JSON object per line; returns the span count."""
-        snap = [s.to_dict() for s in self.spans()]
-        with open(path, "w") as f:
-            for d in snap:
-                f.write(json.dumps(d) + "\n")
-        return len(snap)
-
-    def chrome_trace(self) -> dict:
-        """Chrome-trace / Perfetto ``traceEvents`` JSON (complete "X"
-        events, microsecond timestamps, span events as instants)."""
-        events = []
-        for sp in self.spans():
-            if sp.t_end is None:
-                continue
-            events.append({
-                "name": sp.name, "ph": "X", "pid": 1,
-                "tid": sp.parent_id or 0,
-                "ts": sp.t_start * 1e6,
-                "dur": sp.duration_s * 1e6,
-                "args": {**sp.attrs, "span_id": sp.span_id},
-            })
-            for t, name, attrs in sp.events:
-                events.append({"name": name, "ph": "i", "pid": 1,
-                               "tid": sp.parent_id or 0, "ts": t * 1e6,
-                               "s": "t", "args": dict(attrs)})
-        return {"traceEvents": events,
-                "displayTimeUnit": "ms"}
+            return [build(r) if r in self._spans else None
+                    for r in root_ids]
 
     def reset(self) -> None:
         with self._lock:
             self._spans.clear()
-            self._order.clear()
+            self._finished.clear()
             self.dropped = 0
 
 

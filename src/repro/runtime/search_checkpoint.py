@@ -115,16 +115,17 @@ def task_dir(root: str | Path, task_id: str) -> Path:
 
 def save_task(root: str | Path, task_id: str, seg_idx: int,
               theta: np.ndarray, orders: np.ndarray,
-              rec_states: list[dict]) -> None:
+              rec_states: list[dict], *, tracer: _obs.Tracer) -> None:
     """Checkpoint one batched search task after completing segment
-    `seg_idx - 1` (i.e. `seg_idx` segments are done)."""
+    `seg_idx - 1` (i.e. `seg_idx` segments are done), under a
+    ``checkpoint.save`` span on `tracer`."""
     state = {"theta": np.asarray(theta),
              "orders": np.asarray(orders),
              "recs": {str(i): rs for i, rs in enumerate(rec_states)}}
     d = task_dir(root, task_id)
     t0 = _obs.default_clock()
-    with _obs.get_tracer().span("checkpoint.save", task_id=task_id,
-                                seg_idx=seg_idx) as sp:
+    with tracer.span("checkpoint.save", task_id=task_id,
+                     seg_idx=seg_idx) as sp:
         ckpt.save(d, seg_idx, state,
                   extra_meta={"task_id": task_id,
                               "n_requests": len(rec_states)})
@@ -148,11 +149,11 @@ def _step_ids(d: Path) -> list[int]:
     return sorted(steps, reverse=True)
 
 
-def restore_task(root: str | Path, task_id: str
+def restore_task(root: str | Path, task_id: str, *, tracer: _obs.Tracer
                  ) -> tuple[int, np.ndarray, np.ndarray, list[dict]] | None:
     """Load the newest *readable* checkpoint of a task, or None if it
     has no intact one.  Returns (segments_done, theta, orders, recorder
-    snapshots).
+    snapshots), under a ``checkpoint.restore`` span on `tracer`.
 
     Crash consistency: a corrupt or partial newest step (torn write,
     bitrot) is skipped and the previous good step restores instead —
@@ -160,8 +161,7 @@ def restore_task(root: str | Path, task_id: str
     older segment reaches a bit-identical final state."""
     d = task_dir(root, task_id)
     t0 = _obs.default_clock()
-    with _obs.get_tracer().span("checkpoint.restore",
-                                task_id=task_id) as sp:
+    with tracer.span("checkpoint.restore", task_id=task_id) as sp:
         for step in _step_ids(d):
             try:
                 seg_idx, state = ckpt.restore(d, step)
@@ -211,11 +211,14 @@ class CheckpointGC:
     on drain, and `sweep()` deletes least-recently-used task dirs until
     the total is back under `max_bytes` (None = unbounded; completed-
     task deletion still applies).  On construction the LRU is primed
-    from directory mtimes, so a restarted server sweeps sanely."""
+    from directory mtimes, so a restarted server sweeps sanely.  Each
+    task directory deleted, by `remove()` or `sweep()`, is one
+    ``checkpoint.gc`` span on `tracer`."""
 
     def __init__(self, root: str | Path, max_bytes: int | None = None,
-                 max_tasks: int = 4096):
+                 max_tasks: int = 4096, *, tracer: _obs.Tracer):
         self.root = Path(root)
+        self.tracer = tracer
         self.max_bytes = max_bytes
         self._lru = LRUCache(maxsize=max_tasks)
         self.removed_tasks = 0
@@ -229,15 +232,21 @@ class CheckpointGC:
     def touch(self, task_id: str) -> None:
         self._lru.put(task_id, True)
 
-    def remove(self, task_id: str) -> int:
-        """Drop a completed task's checkpoints (drain-time GC)."""
+    def _delete(self, task_id: str) -> int:
         t0 = _obs.default_clock()
-        freed = delete_task(self.root, task_id)
-        self._lru.discard(task_id)
+        with self.tracer.span("checkpoint.gc", task_id=task_id) as sp:
+            freed = delete_task(self.root, task_id)
+            sp.set(bytes=freed)
         if freed:
             self.removed_tasks += 1
             self.bytes_freed += freed
             _ckpt_metrics("gc", freed, _obs.default_clock() - t0)
+        return freed
+
+    def remove(self, task_id: str) -> int:
+        """Drop a completed task's checkpoints (drain-time GC)."""
+        freed = self._delete(task_id)
+        self._lru.discard(task_id)
         return freed
 
     def total_bytes(self) -> int:
@@ -249,19 +258,12 @@ class CheckpointGC:
         if self.max_bytes is None:
             return []
         swept = []
-        t0 = _obs.default_clock()
         while len(self._lru) > 1 and self.total_bytes() > self.max_bytes:
             item = self._lru.pop_lru()
             if item is None:
                 break
-            task_id = item[0]
-            freed = delete_task(self.root, task_id)
-            if freed:
-                self.removed_tasks += 1
-                self.bytes_freed += freed
-                _ckpt_metrics("gc", freed, _obs.default_clock() - t0)
-                t0 = _obs.default_clock()
-            swept.append(task_id)
+            self._delete(item[0])
+            swept.append(item[0])
         return swept
 
     def stats(self) -> dict:

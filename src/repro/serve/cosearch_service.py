@@ -83,7 +83,7 @@ from ..api import SearchOutcome, SearchRequest
 from ..core.archspec import (GEMMINI_SPEC, bucket_workload,
                              engine_group_key, resolve_spec)
 from ..core.fleet import _TRACED_CFG_FIELDS, search_group_results
-from ..core.mapping import stack_mappings, unstack_mappings
+from ..core.mapping import unstack_mappings
 from ..core.oracle import evaluate_workload
 from ..core.problem import Workload
 from ..core.search import (SearchConfig, _Recorder, _generate_start_point,
@@ -93,7 +93,6 @@ from ..core.search import (SearchConfig, _Recorder, _generate_start_point,
 from ..launch.mesh import auto_pop_shards
 from ..core.fleet import fleet_engine_cache_stats
 from ..obs import telemetry as _obs
-from ..obs.history import HistoryRecorder
 from ..runtime import faults
 from ..runtime import search_checkpoint as sckpt
 
@@ -117,10 +116,8 @@ class ServiceConfig:
     # wall clock directly); tests inject fakes for determinism.
     clock_fn: Callable[[], float] = time.monotonic
     sleep_fn: Callable[[float], None] = time.sleep
-    # Observability: request-lifecycle span budget and the bound on the
-    # npz-backed search-history store (learned-seeding training rows).
+    # Observability: the service tracer's span budget.
     trace_max_spans: int = 100_000
-    history_max_rows: int = 4096
 
     def retry_policy(self) -> faults.RetryPolicy:
         return faults.RetryPolicy(max_retries=self.max_restarts,
@@ -231,10 +228,10 @@ class _BatchTask:
         self._force_shards1 = False
         # Observability taps, wired by the service at registration:
         # trace_event(name, **attrs) fans a fault/degrade event out to
-        # every member request's root span; history records one row per
-        # (request, segment) boundary.
+        # every member request's root span; the service's tracer
+        # records one span per phase of a segment.
         self.trace_event: Callable | None = None
-        self.history: HistoryRecorder | None = None
+        self.tracer: _obs.Tracer = _obs.get_tracer()
 
     def _emit(self, name: str, **attrs) -> None:
         if self.trace_event is not None:
@@ -262,20 +259,25 @@ class _BatchTask:
         request, so accounting matches a direct run member-for-member."""
         self._fresh_recorders()
         thetas, orders = [], []
-        for req, rec in zip(self.requests, self.recs):
-            rcfg = req.config
-            rng = np.random.default_rng(rcfg.seed)
-            starts, best_start_edp = [], float("inf")
-            for _ in range(rcfg.n_start_points):
-                mappings, edp0, best_start_edp = _generate_start_point(
-                    self.workload, rcfg, rng, best_start_edp, rec)
-                rec.best.start_edps.append(edp0)
-                starts.append(mappings)
-            for mappings in starts:
-                rec.record(mappings)
-            thetas.append(theta_from_population(starts,
-                                                self.cspec.free_mask))
-            orders.append(orders_from_population(starts))
+        with self.tracer.span("search.starts",
+                              n=self.spans[-1][1]) as sp:
+            tries = 0       # oracle-checked start tries
+            for req, rec in zip(self.requests, self.recs):
+                rcfg = req.config
+                rng = np.random.default_rng(rcfg.seed)
+                starts, best_start_edp = [], float("inf")
+                for _ in range(rcfg.n_start_points):
+                    mappings, edp0, best_start_edp = _generate_start_point(
+                        self.workload, rcfg, rng, best_start_edp, rec)
+                    rec.best.start_edps.append(edp0)
+                    starts.append(mappings)
+                tries += rec.evals
+                for mappings in starts:
+                    rec.record(mappings)
+                thetas.append(theta_from_population(starts,
+                                                    self.cspec.free_mask))
+                orders.append(orders_from_population(starts))
+            sp.set(tries=tries)
         self.theta = np.concatenate(thetas).astype(np.float32)
         self.orders = np.concatenate(orders)
         self.seg_done = 0
@@ -284,10 +286,7 @@ class _BatchTask:
         if self.started:
             return
         self.started = True
-        restored = None
-        if self.svc_cfg.checkpoint_dir is not None:
-            restored = sckpt.restore_task(self.svc_cfg.checkpoint_dir,
-                                          self.task_id)
+        restored = self._restore()
         if restored is not None:
             seg_done, theta, orders, rec_states = restored
             self._fresh_recorders()
@@ -301,22 +300,26 @@ class _BatchTask:
         if self.seg_done >= len(self.seg_lens):
             self.done = True
 
+    def _restore(self):
+        if self.svc_cfg.checkpoint_dir is None:
+            return None
+        return sckpt.restore_task(self.svc_cfg.checkpoint_dir,
+                                  self.task_id, tracer=self.tracer)
+
     def _checkpoint(self) -> None:
         if self.svc_cfg.checkpoint_dir is None:
             return
         sckpt.save_task(self.svc_cfg.checkpoint_dir, self.task_id,
                         self.seg_done, self.theta, self.orders,
-                        [sckpt.recorder_state(rec) for rec in self.recs])
+                        [sckpt.recorder_state(rec) for rec in self.recs],
+                        tracer=self.tracer)
         if self.checkpoint_hook is not None:
             # chaos taps this to tear the file just written
             self.checkpoint_hook(self.svc_cfg.checkpoint_dir,
                                  self.task_id, self.seg_done)
 
     def _rollback(self) -> None:
-        restored = None
-        if self.svc_cfg.checkpoint_dir is not None:
-            restored = sckpt.restore_task(self.svc_cfg.checkpoint_dir,
-                                          self.task_id)
+        restored = self._restore()
         if restored is not None:
             seg_done, theta, orders, rec_states = restored
             self._fresh_recorders()
@@ -428,73 +431,63 @@ class _BatchTask:
             fault_hook(self.task_id, self.seg_done,
                        tuple(r.request_id for r in self.requests))
         n_steps = self.seg_lens[self.seg_done]
-        run_fused = make_fused_runner(self.workload, self.cfg0)[0]
-
+        tracer = self.tracer
         p_real = self.theta.shape[0]
-        p_pad = _pad_size(p_real, self.svc_cfg.member_buckets)
-        theta = self.theta
-        orders = self.orders
-        if p_pad > p_real:
-            # Replicate the last member: every population op is
-            # per-member, so padding never perturbs the real slices.
-            pad = p_pad - p_real
-            theta = np.concatenate([theta, np.repeat(theta[-1:], pad, 0)])
-            orders = np.concatenate([orders,
-                                     np.repeat(orders[-1:], pad, 0)])
-        # The service rides the sharded engine transparently: the padded
-        # population shards over the "pop" mesh (per-member ops keep the
-        # read-back bit-identical at any shard count), bounded by the
-        # batch config's `shards` knob.  After a shard loss the task is
-        # pinned to the single-device program (bit-identical results).
-        shards = 1 if self._force_shards1 else \
-            auto_pop_shards(p_pad, self.cfg0.shards)
-        theta_j, orders_j = shard_population(
-            jnp.asarray(theta, dtype=jnp.float32), jnp.asarray(orders),
-            shards)
-        (f_seg, o_seg, _), _best = run_fused(
-            theta_j, orders_j, n_full=1, rem=0, seg_len=n_steps,
-            shards=shards)
-        f_seg = np.asarray(f_seg, dtype=float)[0]   # (P_pad, L, 2, nl, 7)
-        o_seg = np.asarray(o_seg)[0]                # (P_pad, L, n_levels)
+        with tracer.span("task.dispatch", population=p_real):
+            run_fused = make_fused_runner(self.workload, self.cfg0)[0]
+            p_pad = _pad_size(p_real, self.svc_cfg.member_buckets)
+            theta = self.theta
+            orders = self.orders
+            if p_pad > p_real:
+                # Replicate the last member: every population op is
+                # per-member, so padding never perturbs the real slices.
+                pad = p_pad - p_real
+                theta = np.concatenate([theta,
+                                        np.repeat(theta[-1:], pad, 0)])
+                orders = np.concatenate([orders,
+                                         np.repeat(orders[-1:], pad, 0)])
+            # The service rides the sharded engine transparently: the
+            # padded population shards over the "pop" mesh (per-member
+            # ops keep the read-back bit-identical at any shard count),
+            # bounded by the batch config's `shards` knob.  After a shard
+            # loss the task is pinned to the single-device program
+            # (bit-identical results).
+            shards = 1 if self._force_shards1 else \
+                auto_pop_shards(p_pad, self.cfg0.shards)
+            theta_j, orders_j = shard_population(
+                jnp.asarray(theta, dtype=jnp.float32), jnp.asarray(orders),
+                shards)
+            (f_seg, o_seg, _), _best = run_fused(
+                theta_j, orders_j, n_full=1, rem=0, seg_len=n_steps,
+                shards=shards)
+        # the host waits here for the device
+        with tracer.span("task.readback"):
+            f_seg = np.asarray(f_seg, dtype=float)[0]  # (P_pad, L, 2, nl, 7)
+            o_seg = np.asarray(o_seg)[0]               # (P_pad, L, n_levels)
 
-        rounded = [unstack_mappings(f_seg[p], o_seg[p])
-                   for p in range(p_real)]
-        for rec, (a, b) in zip(self.recs, self.spans):
-            rec.count(n_steps * (b - a))
-            for p in range(a, b):
-                rec.record(rounded[p])
+        with tracer.span("search.oracle", candidates=p_real) as sp:
+            improved = sum(rec.improved for rec in self.recs)
+            rounded = [unstack_mappings(f_seg[p], o_seg[p])
+                       for p in range(p_real)]
+            for rec, (a, b) in zip(self.recs, self.spans):
+                rec.count(n_steps * (b - a))
+                for p in range(a, b):
+                    rec.record(rounded[p])
+            sp.set(improved=sum(rec.improved for rec in self.recs)
+                   - improved)
         # The rounded population IS the next segment's start state: the
         # fused engine restarts theta from the rounded integer logs each
         # segment, so the host rebuild is bit-identical to the device
         # carry (the PR-4 read-back guarantee).
-        self.theta = theta_from_population(rounded,
-                                           self.cspec.free_mask
-                                           ).astype(np.float32)
-        self.orders = orders_from_population(rounded)
+        with tracer.span("task.rebuild"):
+            self.theta = theta_from_population(rounded,
+                                               self.cspec.free_mask
+                                               ).astype(np.float32)
+            self.orders = orders_from_population(rounded)
         self.seg_done += 1
-        self._record_history()
         if (self.seg_done % self.svc_cfg.checkpoint_every == 0
                 or self.seg_done >= len(self.seg_lens)):
             self._checkpoint()
-
-    def _record_history(self) -> None:
-        """One search-history row per live request at this segment
-        boundary: the running best EDP + its rounded mapping — the
-        learned-seeding training data (`obs.history`)."""
-        if self.history is None:
-            return
-        spec_fp = getattr(self.cspec, "name", "spec")
-        for req, rec in zip(self.requests, self.recs):
-            if req.request_id in self.finalized:
-                continue
-            best = rec.best
-            if not best.best_mappings:
-                continue
-            fs, ords = stack_mappings(best.best_mappings)
-            self.history.record(
-                spec=spec_fp, workload=self.workload.name,
-                segment=self.seg_done, best_edp=best.best_edp,
-                factors=fs, orders=ords, request_id=req.request_id)
 
     # -- timeouts ----------------------------------------------------------
 
@@ -558,7 +551,7 @@ class _GroupTask:
         self.finalized: dict[str, SearchOutcome] = {}
         self.checkpoint_hook: Callable | None = None
         self.trace_event: Callable | None = None
-        self.history: HistoryRecorder | None = None
+        self.tracer: _obs.Tracer = _obs.get_tracer()
 
     def _emit(self, name: str, **attrs) -> None:
         if self.trace_event is not None:
@@ -576,9 +569,13 @@ class _GroupTask:
                                tuple(r.request_id for r in self.requests))
                 specs = [_spec_of(r.config) for r in self.requests]
                 cfgs = [r.config for r in self.requests]
-                results = search_group_results(self.workload, specs,
-                                               self.requests[0].config,
-                                               fused=True, cfgs=cfgs)
+                # the fleet engine's whole shot: starts, dispatch,
+                # read-back and replay
+                with self.tracer.span("task.fleet",
+                                      members=len(self.requests)):
+                    results = search_group_results(
+                        self.workload, specs, self.requests[0].config,
+                        fused=True, cfgs=cfgs)
                 break
             except Exception as exc:   # classified; fatal re-raised
                 action, delay = self.retry.next_action(exc)
@@ -599,17 +596,6 @@ class _GroupTask:
         self._results = results
         self.seg_done = 1
         self.done = True
-        if self.history is not None:
-            for req, sr in zip(self.requests, results):
-                mappings = getattr(sr, "best_mappings", None)
-                if not mappings:
-                    continue
-                fs, ords = stack_mappings(mappings)
-                self.history.record(
-                    spec=getattr(_spec_of(req.config), "name", "spec"),
-                    workload=self.workload.name, segment=1,
-                    best_edp=sr.best_edp, factors=fs, orders=ords,
-                    request_id=req.request_id)
         events = []
         for req, sr in zip(self.requests, results):
             if req.request_id in self.finalized:
@@ -681,7 +667,6 @@ class CoSearchService:
         self.tracer = _obs.Tracer(clock=self.cfg.clock_fn,
                                   max_spans=self.cfg.trace_max_spans)
         self.metrics = _obs.MetricsRegistry()
-        self.history = HistoryRecorder(max_rows=self.cfg.history_max_rows)
         m = self.metrics
         self._c_submitted = m.counter(
             "serve_requests_submitted_total", "requests accepted")
@@ -722,12 +707,16 @@ class CoSearchService:
         self._gc = None
         if self.cfg.checkpoint_dir is not None:
             self._gc = sckpt.CheckpointGC(self.cfg.checkpoint_dir,
-                                          self.cfg.checkpoint_max_bytes)
+                                          self.cfg.checkpoint_max_bytes,
+                                          tracer=self.tracer)
 
     # -- intake ------------------------------------------------------------
 
-    def submit(self, req: SearchRequest) -> str:
+    def submit(self, req: SearchRequest,
+               trace_attrs: dict | None = None) -> str:
         """Enqueue one single-target request; returns its request_id.
+        `trace_attrs` are recorded with it: on its root span, or on the
+        ``dedup_hit`` event of the request it duplicates.
 
         Cross-request dedup: a request whose deterministic fingerprint
         matches one already pending / in flight / completed attaches to
@@ -746,7 +735,8 @@ class CoSearchService:
             root = self._root_span.get(canon)
             if root is not None:
                 self.tracer.add_event(root, "dedup_hit",
-                                      alias=req.request_id)
+                                      alias=req.request_id,
+                                      **(trace_attrs or {}))
             if req.request_id != canon:
                 self._aliases[req.request_id] = canon
             return req.request_id
@@ -763,7 +753,8 @@ class CoSearchService:
         rid = req.request_id
         root = self.tracer.start_span(
             "request", request_id=rid,
-            workload=req.workload.name, priority=req.priority)
+            workload=req.workload.name, priority=req.priority,
+            **(trace_attrs or {}))
         self.tracer.add_event(root, "submitted")
         self._root_span[rid] = root
         self._queue_span[rid] = self.tracer.start_span(
@@ -805,7 +796,7 @@ class CoSearchService:
     def _register_task(self, task) -> None:
         task.checkpoint_hook = self.checkpoint_hook
         task.trace_event = self._trace_event_hook(task)
-        task.history = self.history
+        task.tracer = self.tracer
         self._tasks.append(task)
         self._credits[task.task_id] = 0.0
         self._task_order[task.task_id] = self._task_seq
@@ -917,7 +908,19 @@ class CoSearchService:
         if task is None:
             return []
         task.checkpoint_hook = self.checkpoint_hook
-        seg_spans = self._open_segment_spans(task)
+        # One `service.step` span per advanced task; the task's phase
+        # spans (starts, dispatch, read-back, replay, rebuild,
+        # checkpoint) nest under it on this thread.
+        with self.tracer.span(
+                "service.step", task_id=task.task_id,
+                segment=task.seg_done, batch_size=len(task.requests),
+                kind="fused" if isinstance(task, _BatchTask)
+                else "group") as step_span:
+            return self._step_task(task, step_span.span_id, contain_fatal)
+
+    def _step_task(self, task, step_id: int,
+                   contain_fatal: bool) -> list[ProgressEvent]:
+        seg_spans = self._open_segment_spans(task, step_id)
         try:
             events = task.advance(self.fault_hook)
         except _SplitBatch:
@@ -945,23 +948,26 @@ class CoSearchService:
             self._gc.touch(task.task_id)
             self._gc.sweep()
         if task.done:
-            for req, out in task.final_outcomes():
-                if out.request_id in self._outcomes:
-                    continue
-                self._finalize(out.request_id, out,
-                               count_degraded=True)
-                if out.request_id not in self._frontier \
-                        and out.result is not None:
-                    pt = _point_of(task.workload, req.config, out.result)
-                    if pt is not None:
-                        self._frontier[out.request_id] = pt
+            with self.tracer.span("task.finalize"):
+                for req, out in task.final_outcomes():
+                    if out.request_id in self._outcomes:
+                        continue
+                    self._finalize(out.request_id, out,
+                                   count_degraded=True)
+                    if out.request_id not in self._frontier \
+                            and out.result is not None:
+                        pt = _point_of(task.workload, req.config,
+                                       out.result)
+                        if pt is not None:
+                            self._frontier[out.request_id] = pt
             self._retire(task)
         return events
 
-    def _open_segment_spans(self, task) -> dict[str, int]:
+    def _open_segment_spans(self, task, step_id: int) -> dict[str, int]:
         """One per-segment child span under each live member request's
         root — the batch advances together, so siblings share the
-        interval but each tree stays self-contained."""
+        interval but each tree stays self-contained.  `step_span` names
+        the `service.step` span that holds the segment's phases."""
         spans = {}
         for r in task.requests:
             rid = r.request_id
@@ -969,7 +975,8 @@ class CoSearchService:
                 continue
             spans[rid] = self.tracer.start_span(
                 "segment", parent_id=self._root_span.get(rid),
-                segment=task.seg_done, task_id=task.task_id)
+                segment=task.seg_done, task_id=task.task_id,
+                step_span=step_id)
         return spans
 
     def _close_segment_spans(self, spans: dict[str, int],
@@ -1115,23 +1122,42 @@ class CoSearchService:
                   if r.request_id not in self._outcomes),
             "faults": self.fault_stats(),
             "telemetry": {
-                "spans": len(self.tracer.spans()),
+                "spans": len(self.tracer),
                 "spans_dropped": self.tracer.dropped,
-                "history_rows": len(self.history),
-                "history_dropped": self.history.dropped,
             },
         }
 
     # -- observability endpoints -------------------------------------------
 
+    def note_delivery(self, request_id: str, **attrs) -> None:
+        """Record that a request's outcome was handed out: a
+        ``delivered`` event (with `request_id` as asked, so a dedup
+        alias counts, and `attrs`) on the canonical root span."""
+        root = self._root_span.get(self._rid(request_id))
+        if root is not None:
+            self.tracer.add_event(root, "delivered",
+                                  request_id=request_id, **attrs)
+
     def request_trace(self, request_id: str) -> dict | None:
         """The rooted span tree of one request's lifecycle (submit →
         queue wait → batch join → per-segment advances → drain, fault
-        events inline), or None for unknown ids."""
+        events inline), or None for unknown ids.  Each segment holds
+        the `service.step` span that advanced it, with its phases
+        (starts, dispatch, read-back, replay, checkpoint, ...), unless
+        the tracer's bound has dropped that step already."""
         root = self._root_span.get(self._rid(request_id))
         if root is None:
             return None
-        return self.tracer.tree(root)
+        tree = self.tracer.tree(root)
+        if tree is None:
+            return None
+        segs = [c for c in tree["children"]
+                if c["name"] == "segment" and "step_span" in c["attrs"]]
+        steps = self.tracer.trees([c["attrs"]["step_span"] for c in segs])
+        for seg, step in zip(segs, steps):
+            if step is not None:
+                seg["children"].append(step)
+        return tree
 
     def metrics_text(self) -> str:
         """Prometheus text exposition: the service registry (request /
@@ -1152,10 +1178,6 @@ class CoSearchService:
             g_size.set(st["size"], cache=name)
             g_build.set(st["build_seconds_total"], cache=name)
         return _obs.render_prometheus(self.metrics, _obs.get_metrics())
-
-    def save_history(self, path) -> int:
-        """Persist the search-history store (npz); returns row count."""
-        return self.history.save(path)
 
 
 def _point_of(workload: Workload, cfg: SearchConfig, res):

@@ -33,8 +33,9 @@ Endpoints (all JSON):
   merged with engine-build and checkpoint families from the
   process-global one.
 * ``GET /v1/trace/<request_id>`` — the request's span tree (submit →
-  queue wait → batch join → per-segment advances → drain, with fault
-  events inline); 404 for unknown ids.
+  queue wait → batch join → per-segment advances → drain → delivered,
+  with fault events inline; each segment holds the ``service.step``
+  span that advanced it and its phase spans); 404 for unknown ids.
 * ``GET /v1/healthz`` — liveness.
 
 Request payload::
@@ -298,12 +299,19 @@ class CoSearchServer:
     `port=0` binds an ephemeral port (tests).  All core access is
     serialized under one condition lock; the scheduler thread steps the
     service whenever `busy()` and sleeps on the condition otherwise.
+
+    On the service's tracer (its clock): each stretch the scheduler
+    sleeps with nothing to do is a ``sched.wait`` span; a POST's wait
+    for the lock is ``lock_wait_s`` on its request's root span (or on
+    the ``dedup_hit`` event of a duplicate), and a GET that delivers an
+    outcome adds a ``delivered`` event with its own ``lock_wait_s``.
     """
 
     def __init__(self, service_cfg: ServiceConfig | None = None,
                  host: str = "127.0.0.1", port: int = 0,
                  log=lambda msg: None):
         self.service = CoSearchService(service_cfg)
+        self._clock = self.service.cfg.clock_fn
         self.log = log
         self._host, self._port = host, port
         self._cond = threading.Condition()
@@ -353,13 +361,22 @@ class CoSearchServer:
         is pending, condition-wait when idle.  Fatal task faults are
         contained into error outcomes (`contain_fatal`) so the loop —
         and the server — outlives any single poisoned request."""
+        tracer = self.service.tracer
+        idle = None     # the open sched.wait span
         while not self._stop.is_set():
             with self._cond:
                 if not self.service.busy():
+                    if idle is None:
+                        idle = tracer.start_span("sched.wait")
                     self._cond.wait(timeout=0.1)
                     continue
+                if idle is not None:
+                    tracer.end_span(idle)
+                    idle = None
                 self.service.step(contain_fatal=True)
                 self._cond.notify_all()
+        if idle is not None:
+            tracer.end_span(idle)
 
     def busy(self) -> bool:
         with self._cond:
@@ -369,17 +386,23 @@ class CoSearchServer:
 
     def submit_json(self, body: dict) -> dict:
         req = parse_search_payload(body)
+        t0 = self._clock()
         with self._cond:
+            wait = self._clock() - t0
             before = self.service.stats()["faults"]["dedup_hits"]
-            rid = self.service.submit(req)
+            rid = self.service.submit(req,
+                                      trace_attrs={"lock_wait_s": wait})
             dedup = self.service.stats()["faults"]["dedup_hits"] > before
             self._cond.notify_all()
         return {"request_id": rid, "deduplicated": dedup}
 
     def result_json(self, rid: str) -> tuple[int, dict]:
+        t0 = self._clock()
         with self._cond:
+            wait = self._clock() - t0
             out = self.service.outcome(rid)
             if out is not None:
+                self.service.note_delivery(rid, lock_wait_s=wait)
                 return 200, _outcome_json(out)
             if self.service.knows(rid):
                 return 202, {"request_id": rid, "status": "pending",

@@ -7,6 +7,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.api import SearchRequest
 from repro.core.problem import Layer, Workload
 from repro.core.search import SearchConfig, dosa_search
@@ -52,10 +53,12 @@ def test_torn_checkpoint_falls_back_to_previous_good_step(tmp_path):
     rid = svc.submit(_req(1))
     svc.step()   # seg 1 done; steps 0 and 1 on disk
     task_id = svc._tasks[0].task_id
-    assert sckpt.restore_task(tmp_path, task_id)[0] == 1
+    assert sckpt.restore_task(tmp_path, task_id,
+                              tracer=obs.get_tracer())[0] == 1
     assert tear_checkpoint(tmp_path, task_id, 1)
     # the torn newest step is skipped; the seg-0 baseline restores
-    assert sckpt.restore_task(tmp_path, task_id)[0] == 0
+    assert sckpt.restore_task(tmp_path, task_id,
+                              tracer=obs.get_tracer())[0] == 0
 
     svc2 = CoSearchService(ServiceConfig(bucket_workloads=False,
                                          checkpoint_dir=str(tmp_path)))
@@ -74,7 +77,8 @@ def test_all_checkpoints_torn_replays_from_scratch(tmp_path):
     task_id = svc._tasks[0].task_id
     for step in (0, 1):
         tear_checkpoint(tmp_path, task_id, step)
-    assert sckpt.restore_task(tmp_path, task_id) is None
+    assert sckpt.restore_task(tmp_path, task_id,
+                              tracer=obs.get_tracer()) is None
     svc2 = CoSearchService(ServiceConfig(bucket_workloads=False,
                                          checkpoint_dir=str(tmp_path)))
     svc2.submit(_req(2))
@@ -340,11 +344,16 @@ def test_lru_disk_sweep_bounds_total_bytes(tmp_path):
         d = tmp_path / f"task_t{i}"
         d.mkdir()
         (d / "arrays.npz").write_bytes(bytes(1000))
-    gc = sckpt.CheckpointGC(tmp_path, max_bytes=2000)
+    tracer = obs.Tracer()
+    gc = sckpt.CheckpointGC(tmp_path, max_bytes=2000, tracer=tracer)
     for i in range(4):
         gc.touch(f"t{i}")   # recency order t0 (oldest) .. t3
     swept = gc.sweep()
     assert swept == ["t0", "t1"]
+    # one checkpoint.gc span per directory the sweep deleted
+    assert [(s.attrs["task_id"], s.attrs["bytes"])
+            for s in tracer.spans_named("checkpoint.gc")] \
+        == [("t0", 1000), ("t1", 1000)]
     assert gc.total_bytes() <= 2000
     assert sorted(p.name for p in tmp_path.glob("task_*")) \
         == ["task_t2", "task_t3"]
@@ -360,12 +369,16 @@ def test_checkpoint_fallback_unit(tmp_path):
     theta1 = np.ones_like(theta0)
     orders = np.zeros((2, 1, 3), np.int64)
     rec = {"evals": np.int64(5)}
-    sckpt.save_task(tmp_path, "tid", 1, theta0, orders, [rec])
-    sckpt.save_task(tmp_path, "tid", 2, theta1, orders, [rec])
-    seg, theta, _, recs = sckpt.restore_task(tmp_path, "tid")
+    sckpt.save_task(tmp_path, "tid", 1, theta0, orders, [rec],
+                    tracer=obs.get_tracer())
+    sckpt.save_task(tmp_path, "tid", 2, theta1, orders, [rec],
+                    tracer=obs.get_tracer())
+    seg, theta, _, recs = sckpt.restore_task(tmp_path, "tid",
+                                             tracer=obs.get_tracer())
     assert seg == 2 and theta[0, 0, 0, 0, 0] == 1.0
     assert tear_checkpoint(tmp_path, "tid", 2)
-    seg, theta, _, recs = sckpt.restore_task(tmp_path, "tid")
+    seg, theta, _, recs = sckpt.restore_task(tmp_path, "tid",
+                                             tracer=obs.get_tracer())
     assert seg == 1 and theta[0, 0, 0, 0, 0] == 0.0
     assert int(recs[0]["evals"]) == 5
 

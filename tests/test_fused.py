@@ -272,3 +272,48 @@ def test_fused_device_best_is_min_of_segments(two_layer_workload):
     assert edps.shape == (2, 2) and f_seg.shape[0] == o_seg.shape[0] == 2
     np.testing.assert_allclose(np.asarray(best.edp),
                                np.asarray(edps).min(axis=0))
+
+
+def test_fused_phases_are_named_scopes_that_change_no_numbers(
+        two_layer_workload, monkeypatch):
+    """The fused program's lowered HLO carries op_names under each of
+    the phase scopes `gd`, `round`, `ordering` and `best`; without the
+    scopes the lowered program (its locations aside) and the search's
+    numbers are bit-identical."""
+    import contextlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import search
+    cfg = SearchConfig(steps=40, round_every=20, n_start_points=2, seed=3)
+    key = search._engine_key(two_layer_workload, cfg, "fused")
+    pop, _, _ = search.generate_start_points(two_layer_workload, cfg)
+    free_mask = search._cspec(cfg).free_mask
+
+    def lowered_and_result():
+        search._ENGINE_CACHE.discard(key)
+        run_fused = make_fused_runner(two_layer_workload, cfg)[0]
+        theta = jnp.asarray(search.theta_from_population(pop, free_mask),
+                            dtype=jnp.float32)
+        orders = jnp.asarray(search.orders_from_population(pop))
+        low = run_fused.lower(theta, orders, n_full=2, rem=0, seg_len=20,
+                              shards=1)
+        res = dosa_search(two_layer_workload, cfg, population=2,
+                          fused=True)
+        return low, (res.best_edp, res.n_evals, res.history)
+
+    scoped, got = lowered_and_result()
+    names = re.findall(r'op_name="([^"]*)"',
+                       scoped.as_text(dialect="hlo", debug_info=True))
+    for scope in ("gd", "round", "ordering", "best"):
+        assert any(scope in n.split("/") for n in names), scope
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope",
+                  lambda name: contextlib.nullcontext())
+        plain, want = lowered_and_result()
+    search._ENGINE_CACHE.discard(key)
+    # the StableHLO text leaves out locations, where the scopes live
+    assert plain.as_text() == scoped.as_text()
+    assert got == want

@@ -1,11 +1,8 @@
 """Telemetry spine tests: tracer/span semantics under an injected
 clock, the metrics registry + Prometheus text rendering, per-entry
-engine-cache build accounting, the search-history recorder, and the
-served request lifecycle (span tree, fault events, history rows,
-/v1/metrics families) driven through the real service."""
-import json
-
-import numpy as np
+engine-cache build accounting, and the served request lifecycle (span
+tree, per-step phase spans, fault events, /v1/metrics families) driven
+through the real service."""
 import pytest
 
 from repro.api import SearchRequest
@@ -13,7 +10,6 @@ from repro.core.lru import LRUCache
 from repro.core.problem import Layer, Workload
 from repro.core.search import SearchConfig, _ENGINE_CACHE, dosa_search
 from repro.obs import telemetry as obs
-from repro.obs.history import HistoryRecorder
 from repro.serve.cosearch_service import CoSearchService, ServiceConfig
 
 WL = Workload(layers=(Layer.matmul(16, 16, 16, name="a"),), name="wa")
@@ -112,22 +108,22 @@ def test_eviction_drops_finished_never_open_roots():
     assert len(live) <= 5
 
 
-def test_jsonl_and_chrome_trace_export(tmp_path):
-    clk = _Clock()
-    tr = obs.Tracer(clock=clk)
-    with tr.span("work", kind="t") as sp:
-        clk.tick(0.5)
-        sp.event("midpoint")
-    p = tmp_path / "spans.jsonl"
-    assert tr.export_jsonl(p) == 1
-    rec = json.loads(p.read_text().splitlines()[0])
-    assert rec["name"] == "work" and rec["duration_s"] == 0.5
-
-    ct = tr.chrome_trace()
-    phs = [e["ph"] for e in ct["traceEvents"]]
-    assert "X" in phs and "i" in phs
-    x = next(e for e in ct["traceEvents"] if e["ph"] == "X")
-    assert x["dur"] == pytest.approx(0.5e6)   # microseconds
+def test_eviction_is_bounded_and_keeps_an_old_open_root():
+    """Past `max_spans` the oldest finished span goes, one per insert:
+    the open root that came first survives 2x the cap of inserts, the
+    store stays at the cap and `dropped` counts the rest."""
+    cap = 50
+    tr = obs.Tracer(clock=_Clock(), max_spans=cap)
+    root = tr.start_span("request")       # stays open
+    for i in range(2 * cap):
+        with tr.span("seg", parent_id=root, i=i):
+            pass
+    live = tr.spans()
+    assert len(live) == len(tr) == cap
+    assert live[0].span_id == root and live[0].t_end is None
+    assert tr.dropped == 2 * cap + 1 - cap
+    # the newest finished spans are the ones kept
+    assert [s.attrs["i"] for s in live[1:]] == list(range(cap + 1, 2 * cap))
 
 
 # ---------------------------------------------------------------------------
@@ -296,41 +292,7 @@ def test_compile_cache_dir_env_wins_else_fixed_checkout_path(
 
 
 # ---------------------------------------------------------------------------
-# Search-history recorder
-# ---------------------------------------------------------------------------
-
-def test_history_roundtrip_ragged(tmp_path):
-    rec = HistoryRecorder()
-    for i, n_layers in enumerate((1, 3)):
-        rec.record(spec="tpu_v5e", workload=f"w{i}", segment=i + 1,
-                   best_edp=1.5 * (i + 1), request_id=f"r{i}",
-                   factors=np.ones((n_layers, 2, 3, 7)),
-                   orders=np.zeros((n_layers, 3)))
-    p = tmp_path / "history.npz"
-    assert rec.save(p) == 2
-    back = HistoryRecorder.load(p)
-    rows = back.rows()
-    assert [(r.spec, r.workload, r.request_id, r.segment, r.best_edp)
-            for r in rows] == [("tpu_v5e", "w0", "r0", 1, 1.5),
-                               ("tpu_v5e", "w1", "r1", 2, 3.0)]
-    assert rows[1].factors.shape == (3, 2, 3, 7)
-    assert rows[1].factors.dtype == np.float32
-    assert rows[1].orders.dtype == np.int32
-    assert back.rows("r0")[0].workload == "w0"
-
-
-def test_history_bounded_drop_oldest():
-    rec = HistoryRecorder(max_rows=3)
-    for i in range(5):
-        rec.record(spec="s", workload="w", segment=i, best_edp=float(i),
-                   factors=np.ones((1, 2, 3, 7)),
-                   orders=np.zeros((1, 3)))
-    assert len(rec) == 3 and rec.dropped == 2
-    assert [r.segment for r in rec.rows()] == [2, 3, 4]
-
-
-# ---------------------------------------------------------------------------
-# Served request lifecycle: span tree, metrics, history
+# Served request lifecycle: span tree, phase spans, metrics
 # ---------------------------------------------------------------------------
 
 def test_served_request_full_span_tree_and_history():
@@ -356,16 +318,10 @@ def test_served_request_full_span_tree_and_history():
     assert segs[-1]["attrs"]["best_edp"] == out.result.best_edp
     assert svc.request_trace("doesnotexist") is None
 
-    # one history row per rounding segment, EDP matching the event
-    # stream (the learned-seeding dataset contract)
-    events = svc.events(rid)
-    rows = svc.history.rows(rid)
-    assert [r.segment for r in rows] == [ev.segment for ev in events]
-    assert [r.best_edp for r in rows] == \
-        [ev.best_edp for ev in events]
-    assert rows[-1].best_edp == out.result.best_edp
-    assert rows[0].workload == "wa"
-    assert rows[0].factors.ndim == 4
+    # each segment names the service.step span that ran it
+    steps = {s.span_id: s for s in svc.tracer.spans_named("service.step")}
+    assert [steps[s["attrs"]["step_span"]].attrs["segment"]
+            for s in segs] == [0, 1]
 
     m = _parse_prometheus(svc.metrics_text())
     assert m["serve_requests_submitted_total"] >= 1.0
@@ -379,8 +335,74 @@ def test_served_request_full_span_tree_and_history():
 
     st = svc.stats()
     assert st["n_batches"] >= 1 and st["n_grouped_batches"] == 0
-    assert st["telemetry"]["spans"] >= 4
-    assert st["telemetry"]["history_rows"] == len(svc.history)
+    assert st["telemetry"]["spans"] == len(svc.tracer.spans()) >= 4
+    assert set(st["telemetry"]) == {"spans", "spans_dropped"}
+
+
+def _children(tracer, span_id):
+    return [c["name"] for c in tracer.tree(span_id)["children"]]
+
+
+def test_served_step_phase_spans_on_the_service_tracer(tmp_path):
+    """On a fake clock, every `service.step` holds the task's phases in
+    order: on the first segment only, the look for a checkpoint to
+    resume, start generation and the seg-0 checkpoint; then dispatch, read-back, oracle replay, rebuild, checkpoint, and on the
+    last segment finalize and checkpoint GC.  Nothing reaches the
+    (enabled) global tracer."""
+    dosa_search(WL, _cfg(41), population=2, fused=True)   # engine warm
+    glob = obs.Tracer()
+    old = obs.set_tracer(glob)
+    try:
+        svc = CoSearchService(ServiceConfig(
+            bucket_workloads=False, clock_fn=_Clock(),
+            checkpoint_dir=str(tmp_path / "ck")))
+        rid = svc.submit(_req(41))
+        out = svc.drain()[rid]
+    finally:
+        obs.set_tracer(old)
+    assert out.status == "ok"
+    assert glob.spans() == []
+
+    tr = svc.tracer
+    steps = tr.spans_named("service.step")
+    assert [s.attrs["segment"] for s in steps] == [0, 1]
+    assert all(s.attrs["kind"] == "fused" and s.attrs["batch_size"] == 1
+               and s.parent_id is None for s in steps)
+    body = ["task.dispatch", "task.readback", "search.oracle",
+            "task.rebuild", "checkpoint.save"]
+    assert _children(tr, steps[0].span_id) == \
+        ["checkpoint.restore", "search.starts", "checkpoint.save"] + body
+    assert _children(tr, steps[1].span_id) == \
+        body + ["task.finalize", "checkpoint.gc"]
+
+    (starts,) = tr.spans_named("search.starts")
+    assert starts.attrs["n"] == 2 and starts.attrs["tries"] >= 2
+    for sp in tr.spans_named("search.oracle"):
+        assert sp.attrs["candidates"] == 2
+        assert 0 <= sp.attrs["improved"] <= sp.attrs["candidates"]
+    # samples: tries + the starts' own replay + GD steps + candidates
+    assert out.result.n_evals == starts.attrs["tries"] + 2 + 2 * (4 + 2)
+
+
+def test_direct_fused_oracle_spans_count_candidates():
+    """A direct fused search's `search.oracle` spans count every
+    replayed candidate (starts x segments) and the improvements among
+    them; `search.starts` counts its oracle-checked tries."""
+    cfg = SearchConfig(steps=6, round_every=2, n_start_points=3, seed=5)
+    tr = obs.Tracer()
+    old = obs.set_tracer(tr)
+    try:
+        res = dosa_search(WL, cfg, population=2, fused=True)
+    finally:
+        obs.set_tracer(old)
+    oracle = tr.spans_named("search.oracle")
+    assert sum(s.attrs["candidates"] for s in oracle) == 3 * 3
+    assert all(0 <= s.attrs["improved"] <= s.attrs["candidates"]
+               for s in oracle)
+    (starts,) = tr.spans_named("search.starts")
+    assert starts.attrs["n"] == 3
+    assert 3 <= starts.attrs["tries"] <= 3 * cfg.max_reject_tries
+    assert res.n_evals == starts.attrs["tries"] + 3 + 3 * (6 + 3)
 
 
 def test_trace_records_retry_and_backoff_events():

@@ -232,3 +232,85 @@ def test_http_trace_span_tree_and_404(server):
     assert kids[0] == "queue_wait"
     assert kids.count("segment") == 2
     assert _get(base, "/v1/trace/doesnotexist")[0] == 404
+
+
+def test_http_trace_segments_hold_their_step_phases(tmp_path):
+    """Each segment of /v1/trace/<rid> holds the `service.step` span
+    that advanced it, and under it the phase spans: dispatch, read-back,
+    oracle replay and the checkpoint save."""
+    srv = CoSearchServer(ServiceConfig(bucket_workloads=False,
+                                       checkpoint_dir=str(tmp_path)))
+    host, port = srv.start()
+    base = f"http://{host}:{port}"
+    try:
+        _, sub = _post(base, "/v1/search",
+                       {"workload": WL_JSON,
+                        "config": dict(CFG_JSON, seed=61)})
+        rid = sub["request_id"]
+        assert srv.wait_idle(timeout=300)
+        code, out = _get(base, f"/v1/trace/{rid}")
+    finally:
+        srv.stop()
+    assert code == 200
+    segs = [c for c in out["trace"]["children"] if c["name"] == "segment"]
+    assert [s["attrs"]["segment"] for s in segs] == [0, 1]
+    for seg in segs:
+        (step,) = seg["children"]
+        assert step["name"] == "service.step"
+        assert step["span_id"] == seg["attrs"]["step_span"]
+        assert step["attrs"]["task_id"] == seg["attrs"]["task_id"]
+        phases = [c["name"] for c in step["children"]]
+        for name in ("task.dispatch", "task.readback", "search.oracle",
+                     "checkpoint.save"):
+            assert name in phases, (name, phases)
+        oracle = step["children"][phases.index("search.oracle")]
+        assert oracle["attrs"]["candidates"] == CFG_JSON["n_start_points"]
+    assert "search.starts" in [c["name"] for c in segs[0]["children"][0]
+                               ["children"]]
+
+
+def test_lock_waits_on_post_and_delivering_get():
+    """A POST's wait for the service lock lands on its request's root
+    span (a duplicate's on the `dedup_hit` event); a GET that delivers
+    the outcome adds a `delivered` event to the canonical root, alias
+    deliveries included, and a pending poll records nothing."""
+    srv = CoSearchServer(ServiceConfig(bucket_workloads=False))
+    body = {"workload": WL_JSON, "config": dict(CFG_JSON, seed=57)}
+    canon = srv.submit_json(dict(body, request_id="lw-canon"))
+    alias = srv.submit_json(dict(body, request_id="lw-alias"))
+    assert alias["deduplicated"] and not canon["deduplicated"]
+    assert srv.result_json("lw-canon")[0] == 202
+    srv.service.drain()
+    assert srv.result_json("lw-canon")[0] == 200
+    assert srv.result_json("lw-alias")[0] == 200
+
+    tree = srv.service.request_trace("lw-alias")
+    assert tree["attrs"]["request_id"] == "lw-canon"
+    assert tree["attrs"]["lock_wait_s"] >= 0.0
+    events = {e["name"]: [] for e in tree["events"]}
+    for e in tree["events"]:
+        events[e["name"]].append(e["attrs"])
+    (dup,) = events["dedup_hit"]
+    assert dup["alias"] == "lw-alias" and dup["lock_wait_s"] >= 0.0
+    assert [d["request_id"] for d in events["delivered"]] == \
+        ["lw-canon", "lw-alias"]
+    assert all(d["lock_wait_s"] >= 0.0 for d in events["delivered"])
+    assert [e["name"] for e in tree["events"]][-3:] == \
+        ["drain", "delivered", "delivered"]
+
+
+def test_scheduler_idle_is_a_sched_wait_span(server):
+    """The scheduler's idle stretches are closed `sched.wait` spans on
+    the service's tracer, none of them overlapping a `service.step`."""
+    srv, base = server
+    _post(base, "/v1/search",
+          {"workload": WL_JSON, "config": dict(CFG_JSON, seed=58)})
+    assert srv.wait_idle(timeout=300)
+    tr = srv.service.tracer
+    waits = [s for s in tr.spans_named("sched.wait") if s.t_end is not None]
+    steps = tr.spans_named("service.step")
+    assert waits and steps
+    for w in waits:
+        assert w.parent_id is None
+        assert not any(s.t_start < w.t_end and w.t_start < s.t_end
+                       for s in steps)
