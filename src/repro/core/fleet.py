@@ -53,7 +53,7 @@ from ..sharding.rules import member_spec, segment_member_spec
 from .archspec import (ArchSpec, CompiledSpec, engine_group_key,
                        resolve_spec)
 from .lru import LRUCache
-from .mapping import Mapping, stack_mappings, unstack_mappings
+from .mapping import Mapping, stack_mappings
 from .model import (PopulationBest, SpecHW, capacities,
                     infer_hw_population_spec,
                     layer_c_pe_spec, layer_el_all_orderings_population_spec,
@@ -70,7 +70,7 @@ from .search import (_Recorder, _adam_scan, _cd_orderings,
                      _spatial_cap_penalty, SearchConfig, SearchResult,
                      build_f, dosa_search, make_segment_runner,
                      orders_from_population,
-                     select_orderings_population_spec,
+                     select_orderings_population_spec, stack_population,
                      theta_from_population)
 
 # Capacity sentinel for unconstrained levels.  Finite on purpose: the
@@ -691,11 +691,9 @@ def search_group_results(workload: Workload, specs: list[ArchSpec],
                 f_seg, o_seg = f_seg[:, inv], o_seg[:, inv]
         for s, n_steps in enumerate(seg_lens):
             with tracer.span("fleet.oracle", segment=s):
-                for cspec, rec, (a, b) in zip(cspecs, recs, spans):
+                for rec, (a, b) in zip(recs, spans):
                     rec.count(n_steps * (b - a))
-                    for p in range(a, b):
-                        rec.record(
-                            unstack_mappings(f_seg[s, p], o_seg[s, p]))
+                    rec.record_batch(f_seg[s, a:b], o_seg[s, a:b])
     else:
         for n_steps in seg_lens:
             theta = run_segment(theta, orders, sp_stack, n_steps=n_steps)
@@ -717,8 +715,7 @@ def search_group_results(workload: Workload, specs: list[ArchSpec],
                     for ms, no in zip(rounded, sel):
                         for mp, o in zip(ms, no):
                             mp.order = o
-                for ms in rounded:
-                    rec.record(ms)
+                rec.record_batch(*stack_population(rounded))
                 new_thetas.append(
                     theta_from_population(rounded, cspec.free_mask))
                 new_orders.append(orders_from_population(rounded))

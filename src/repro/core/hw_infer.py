@@ -17,8 +17,27 @@ import numpy as np
 from .arch import GemminiHW
 from .archspec import GEMMINI_SPEC, HWConfig, compile_spec, resolve_spec
 from .mapping import SPATIAL, Mapping
-from .oracle import _caps
+from .oracle import _caps, _masked_words
 from .problem import Layer
+
+
+def _hw_point(cspec, pe_dim: int, req) -> HWConfig:
+    """The hardware point for a PE side and per-searched-level words:
+    the side capped at the spec's limit (or fixed by its silicon), the
+    capacities rounded up (Sec. 6.1)."""
+    pe_dim = min(pe_dim, cspec.spec.max_pe_dim)
+    if cspec.spec.fixed_pe_dim is not None:
+        pe_dim = cspec.spec.fixed_pe_dim
+    return HWConfig(pe_dim=pe_dim, cap_kb=cspec.round_caps(req))
+
+
+def _spec_typed(cspec, hw: HWConfig):
+    """`GemminiHW` for the Gemmini spec (legacy type expected by
+    callers/tests), the `HWConfig` otherwise."""
+    if cspec.spec is GEMMINI_SPEC:
+        return GemminiHW(pe_dim=hw.pe_dim, acc_kb=hw.cap_kb[0],
+                         sp_kb=hw.cap_kb[1])
+    return hw
 
 
 def minimal_hw_spec(mappings: list[Mapping], layers: list[Layer],
@@ -35,20 +54,28 @@ def minimal_hw_spec(mappings: list[Mapping], layers: list[Layer],
             words = sum(float(caps[i, t]) for t in range(3)
                         if cspec.b_matrix[i, t])
             req[j] = max(req[j], words)
-    pe_dim = min(pe_dim, cspec.spec.max_pe_dim)
-    if cspec.spec.fixed_pe_dim is not None:
-        pe_dim = cspec.spec.fixed_pe_dim
-    return HWConfig(pe_dim=pe_dim, cap_kb=cspec.round_caps(req))
+    return _hw_point(cspec, pe_dim, req)
 
 
 def minimal_hw_for(cspec, mappings: list[Mapping], layers: list[Layer]):
     """Spec-dispatching form: `GemminiHW` for the Gemmini spec (legacy
     type expected by callers/tests), `HWConfig` otherwise."""
-    hw = minimal_hw_spec(mappings, layers, spec=cspec)
-    if resolve_spec(cspec).spec is GEMMINI_SPEC:
-        return GemminiHW(pe_dim=hw.pe_dim, acc_kb=hw.cap_kb[0],
-                         sp_kb=hw.cap_kb[1])
-    return hw
+    cspec = resolve_spec(cspec)
+    return _spec_typed(cspec, minimal_hw_spec(mappings, layers, spec=cspec))
+
+
+def minimal_hw_from_caps(cspec, caps: np.ndarray, fs: np.ndarray):
+    """`minimal_hw_for` from tile words already computed: `caps`
+    (L, n_levels, 3) as `oracle.evaluate_population` returns them for
+    one member, `fs` (L, 2, n_levels, 7) that member's integer factors.
+    No per-layer `_caps` walk."""
+    cspec = resolve_spec(cspec)
+    pe_dim = 1
+    for (lvl, d) in cspec.spatial_sites:
+        pe_dim = max(pe_dim, int(np.round(fs[:, SPATIAL, lvl, d]).max()))
+    req = [max(0.0, float(np.max(_masked_words(caps, cspec, i))))
+           for i in cspec.searched_levels]
+    return _spec_typed(cspec, _hw_point(cspec, pe_dim, req))
 
 
 def minimal_hw(mappings: list[Mapping], layers: list[Layer]) -> GemminiHW:

@@ -24,6 +24,13 @@ Deliberate fidelity details:
 * rejects invalid mappings (capacity overflow under fixed hardware or
   fixed-silicon levels, non-divisor factors, PE overflow) by returning
   `inf`.
+
+`evaluate` / `evaluate_workload` walk one mapping at a time and stay the
+iterative reference.  `evaluate_population` is their batched form for
+the search's oracle replay: a whole population of workload mappings in
+one call, the same float64 arithmetic in the same order, vectorised
+over (members, layers), so every network EDP it returns is
+bit-identical to `evaluate_workload`'s (tests/test_oracle_batch.py).
 """
 from __future__ import annotations
 
@@ -252,3 +259,166 @@ def evaluate_workload(mappings: list[Mapping], layers, hw=None,
         e_tot += r.energy * layer.repeat
         l_tot += r.latency * layer.repeat
     return e_tot * l_tot, results
+
+
+def _masked_words(caps: np.ndarray, cspec, i: int):
+    """`sum(caps[i, t] for t if B[i, t])` over trailing (n_levels, 3)
+    axes, accumulated in `evaluate`'s order (W, I, O from int 0)."""
+    words = 0
+    for t in range(3):
+        if cspec.b_matrix[i, t]:
+            words = words + caps[..., i, t]
+    return words
+
+
+def evaluate_population(fs, orders, layers, hw=None,
+                        quantize_dram: bool = True, spec=None):
+    """Network EDPs of B workload mappings in one call.
+
+    `fs` (B, L, 2, n_levels, 7) factors and `orders` (B, L, n_levels)
+    ordering choices, as the search reads them back.  Returns `(edps,
+    caps)`: the (B,) network EDPs, each bit-identical to
+    `evaluate_workload(unstack_mappings(fs[b], orders[b]), layers, hw,
+    quantize_dram, spec)[0]` (`inf` for every member `evaluate` rejects),
+    and the (B, L, n_levels, 3) tile words of `_caps` (meaningless for
+    an invalid member).  Integer quantities are exact int64 products
+    where `evaluate` uses Python ints; every float sum keeps
+    `evaluate`'s order."""
+    cspec = resolve_spec(spec)
+    nl, backing = cspec.n_levels, cspec.backing
+    fs = np.asarray(fs, dtype=np.float64)
+    orders = np.asarray(orders, dtype=np.int64)
+    dims = np.array([lay.dims for lay in layers], dtype=np.float64)
+    with np.errstate(all="ignore"):
+        # ----- validity, per (member, layer)
+        ok = np.isclose(fs.prod(axis=(2, 3)), dims, rtol=1e-9,
+                        atol=1e-6).all(axis=-1)
+        ok &= ~(fs < 1.0 - 1e-9).any(axis=(2, 3, 4))
+        fr = np.round(fs)
+        ok &= np.isclose(fs, fr, rtol=1e-5, atol=1e-6).all(axis=(2, 3, 4))
+        for d in range(NDIMS):
+            if d not in cspec.spec.level0_temporal_dims:
+                ok &= fr[:, :, TEMPORAL, 0, d] == 1
+        fi = np.where(ok[:, :, None, None, None], fr, 1.0).astype(np.int64)
+
+        # ----- tile words (`_caps`)
+        ext = (np.cumprod(fi[:, :, TEMPORAL], axis=2)
+               * fi[:, :, SPATIAL].prod(axis=2)[:, :, None, :])
+        e = [ext[..., d] for d in range(NDIMS)]
+        ws = np.array([lay.wstride for lay in layers])[:, None]
+        hs = np.array([lay.hstride for lay in layers])[:, None]
+        pin = ws * (e[P] - 1) + e[R]
+        qin = hs * (e[Q] - 1) + e[S]
+        caps = np.stack([e[R] * e[S] * e[C] * e[K],
+                         e[C] * e[N] * pin * qin,
+                         e[P] * e[Q] * e[K] * e[N]],
+                        axis=-1).astype(np.float64)
+
+        sites = [fi[:, :, SPATIAL, lvl, d] for (lvl, d) in
+                 cspec.spatial_sites]
+        pe_dim = (np.max(sites, axis=0) if sites
+                  else np.ones(ok.shape, dtype=np.int64))
+        fixed = dict(cspec.fixed_capacity)
+        if hw is None:
+            ok &= pe_dim <= cspec.spec.max_pe_dim
+            side = cspec.spec.fixed_pe_dim or pe_dim
+            c_pe = np.broadcast_to(side * side, ok.shape).astype(np.float64)
+            cap_words = [np.inf] * nl
+            for i in cspec.searched_levels:
+                cap_words[i] = _masked_words(caps, cspec, i)
+            for i, words in fixed.items():
+                cap_words[i] = words
+            check = list(fixed)
+        else:
+            c_pe, cap_words = cspec.hw_words(hw)
+            ok &= pe_dim <= hw.pe_dim
+            check = list(cspec.searched_levels) + list(fixed)
+        for i in check:
+            ok &= ~(_masked_words(caps, cspec, i) > cap_words[i] + 1e-6)
+
+        # ----- traffic (`evaluate`'s loop over the tensor chains)
+        ot = ORDER_TABLE[orders]                        # (B, L, nl, 7)
+        ft = np.take_along_axis(fi[:, :, TEMPORAL], ot, axis=-1)
+
+        def fill_multiplier(level, tensor):
+            mult = np.ones(ok.shape, dtype=np.int64)
+            seen = np.zeros(ok.shape, dtype=bool)
+            for j in range(level + 1, nl):
+                for k in range(NDIMS):          # innermost -> outermost
+                    f = ft[:, :, j, k]
+                    rel = REL[tensor][ot[:, :, j, k]]
+                    mult = np.where(rel | seen, mult * f, mult)
+                    seen |= rel & (f > 1)
+            return mult
+
+        def discount(level, tensor):
+            disc = np.ones(ok.shape, dtype=np.int64)
+            for d in range(NDIMS):
+                if not REL[tensor, d]:
+                    disc = disc * fi[:, :, SPATIAL, level, d]
+            return disc
+
+        macs = np.array([int(np.prod(np.asarray(lay.dims),
+                                     dtype=np.float64)) for lay in layers])
+        reads = np.zeros((nl,) + ok.shape)
+        writes = np.zeros((nl,) + ok.shape)
+        dram_parts = []
+        fills = {(t, i): caps[:, :, i, t] * fill_multiplier(i, t)
+                 for t, levels in cspec.tensor_levels.items()
+                 for i in levels}
+        for t in (W_T, I_T):
+            levels = cspec.tensor_levels[t]
+            reads[levels[0]] += macs / discount(levels[0], t)
+            for pos in range(1, len(levels)):
+                i, prev = levels[pos], levels[pos - 1]
+                amount = fills[(t, prev)] / discount(i, t)
+                reads[i] += amount
+                if i == backing:
+                    dram_parts.append(amount)
+            for i in levels:
+                if i != backing:
+                    writes[i] += fills[(t, i)]
+
+        acc_lvl, top = cspec.tensor_levels[O_T]
+        upd = macs / discount(acc_lvl, O_T)
+        nres = fills[(O_T, acc_lvl)]
+        refetch = np.maximum(nres - caps[:, :, top, O_T], 0.0)
+        writes[acc_lvl] += upd + refetch
+        reads[acc_lvl] += (upd - nres) + nres
+        writes[top] += nres
+        reads[top] += refetch
+        dram_parts += [nres, refetch]
+
+        accesses = reads + writes
+        if quantize_dram:
+            block = cspec.spec.dram_block_words
+            total = np.zeros(ok.shape, dtype=np.int64)
+            for p in dram_parts:
+                blocks = np.ceil(np.where(p > 0, p, 0.0) / block)
+                total = total + blocks.astype(np.int64) * block
+            accesses[backing] = total
+
+        bw = cspec.bandwidth(c_pe)
+        mem_lat = accesses[0] / bw[0]
+        for i in range(1, nl):
+            mem_lat = np.maximum(mem_lat, accesses[i] / bw[i])
+        utilized = np.ones(ok.shape, dtype=np.int64)
+        for s in sites:
+            utilized = utilized * s
+        latency = np.maximum(macs / utilized, mem_lat)
+
+        epa = cspec.epa(c_pe, cap_words)
+        acc_energy = accesses[0] * epa[0]
+        for i in range(1, nl):
+            acc_energy = acc_energy + accesses[i] * epa[i]
+        energy = macs * cspec.spec.epa_mac + acc_energy
+
+        # ----- network sums (Eq. 14), layer by layer as in
+        # `evaluate_workload`
+        e_tot = np.zeros(ok.shape[0])
+        l_tot = np.zeros(ok.shape[0])
+        for li, lay in enumerate(layers):
+            e_tot = e_tot + energy[:, li] * lay.repeat
+            l_tot = l_tot + latency[:, li] * lay.repeat
+        edps = np.where(ok.all(axis=1), e_tot * l_tot, np.inf)
+    return edps, caps

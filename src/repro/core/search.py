@@ -81,7 +81,8 @@ from .arch import GemminiHW
 from .archspec import (ArchSpec, CompiledSpec, GEMMINI_SPEC, HWConfig,
                        compile_spec, resolve_spec)
 from .cosa import cosa_map_workload
-from .hw_infer import minimal_hw_for, random_hw_for
+from .hw_infer import (minimal_hw_for, minimal_hw_from_caps,
+                       random_hw_for)
 from .lru import LRUCache
 from .mapping import SPATIAL, TEMPORAL, Mapping, stack_mappings
 from .mapping import unstack_mappings
@@ -94,7 +95,7 @@ from .model import (PopulationBest, SpecHW, capacities,
                     population_edp_spec,
                     validity_penalty, workload_eval_spec,
                     _spec_hw_from_params)
-from .oracle import evaluate_workload
+from .oracle import evaluate_population, evaluate_workload
 from .problem import Workload
 from .rounding import (round_all, round_population, rounding_tables,
                        _round_population_core)
@@ -151,6 +152,14 @@ def theta_from_population(population: list[list[Mapping]],
 def orders_from_population(population: list[list[Mapping]]) -> np.ndarray:
     """(P, L, n_levels) per-level ordering choices for a population."""
     return np.stack([np.stack([m.order for m in ms]) for ms in population])
+
+
+def stack_population(population: list[list[Mapping]]
+                     ) -> tuple[np.ndarray, np.ndarray]:
+    """(P, L, 2, n_levels, 7) factors and (P, L, n_levels) orders of a
+    population of workload mappings (`stack_mappings` per member)."""
+    fs, orders = zip(*(stack_mappings(ms) for ms in population))
+    return np.stack(fs), np.stack(orders)
 
 
 @dataclasses.dataclass
@@ -732,6 +741,16 @@ def select_orderings_population(fs_pop: np.ndarray, strides: np.ndarray,
 # Oracle accounting shared by both engines
 # ---------------------------------------------------------------------------
 
+def _oracle_candidates(path: str, n: int) -> None:
+    """Count `n` replayed candidates by replay path into the global
+    registry: `oracle_batch_candidates_total` (one batched oracle call
+    over a read-back) or `oracle_scalar_candidates_total` (one
+    `_Recorder.record` each)."""
+    _obs.get_metrics().counter(
+        f"oracle_{path}_candidates_total",
+        f"rounded candidates replayed on the {path} oracle path").inc(n)
+
+
 def _oracle_edp(mappings, workload, cfg, cspec: CompiledSpec) -> float:
     if cfg.latency_model is not None:
         return cfg.latency_model(mappings, workload)
@@ -750,7 +769,7 @@ class _Recorder:
     """Sample accounting shared by the sequential and batched drivers:
     every differentiable-model step and every oracle evaluation counts
     as one sample (Sec. 6.3).  `improved` counts the recorded candidates
-    that set a new best (and so paid for `minimal_hw_for`)."""
+    that set a new best (and so paid for their mappings and hardware)."""
 
     def __init__(self, workload: Workload, cfg: SearchConfig,
                  cspec: CompiledSpec):
@@ -768,10 +787,49 @@ class _Recorder:
     def count(self, n: int = 1) -> None:
         self.evals += n
 
+    @property
+    def oracle_path(self) -> str:
+        """How `record_batch` replays: "scalar" (one `record` a
+        candidate) where the configuration prices a candidate by more
+        than the mappings alone — a learned latency model, or Sec. 6.5's
+        buffers re-derived per candidate — else "batch"."""
+        cfg = self.cfg
+        if cfg.latency_model is not None or (cfg.fixed_hw is not None
+                                             and cfg.fix_pe_only):
+            return "scalar"
+        return "batch"
+
+    def record_batch(self, fs: np.ndarray, orders: np.ndarray) -> None:
+        """Oracle-evaluate B rounded candidates — `fs` (B, L, 2, n_levels,
+        7), `orders` (B, L, n_levels) — and update the running best
+        exactly as B calls to `record` in member order would: one
+        `evaluate_population` call, and `Mapping` objects and hardware
+        only for a member that sets a new best."""
+        if self.oracle_path == "scalar":
+            for f, o in zip(fs, orders):
+                self.record(unstack_mappings(f, o))
+            return
+        cfg, best, cspec = self.cfg, self.best, self.cspec
+        edps, caps = evaluate_population(fs, orders, self.workload.layers,
+                                         hw=cfg.fixed_hw, spec=cspec)
+        _oracle_candidates("batch", len(edps))
+        for b, edp in enumerate(edps.tolist()):
+            self.evals += 1
+            if edp < best.best_edp:
+                self.improved += 1
+                best.best_edp = edp
+                best.best_mappings = [
+                    m.copy() for m in unstack_mappings(fs[b], orders[b])]
+                best.best_hw = (cfg.fixed_hw if cfg.fixed_hw is not None
+                                else minimal_hw_from_caps(cspec, caps[b],
+                                                          fs[b]))
+            best.history.append((self.evals, best.best_edp))
+
     def record(self, mappings: list[Mapping]) -> float:
         """Oracle-evaluate a rounded candidate, update the running best."""
         cfg, best = self.cfg, self.best
         edp = _oracle_edp(mappings, self.workload, cfg, self.cspec)
+        _oracle_candidates("scalar", 1)
         self.evals += 1
         if edp < best.best_edp:
             self.improved += 1
@@ -990,8 +1048,7 @@ def _dosa_search_batched(workload: Workload, cfg: SearchConfig,
     for lo in range(0, len(starts), population):
         chunk = starts[lo:lo + population]
         n_real = len(chunk)
-        for mappings in chunk:
-            rec.record(mappings)
+        rec.record_batch(*stack_population(chunk))
         # Pad a ragged final chunk to `population` with replicas of the
         # last member: every population op is per-member, so padding
         # never perturbs the real slices, and ONE program shape covers
@@ -1035,10 +1092,10 @@ def _dosa_search_batched(workload: Workload, cfg: SearchConfig,
                     for ms, no in zip(rounded_pop, new_orders):
                         for mp, o in zip(ms, no):
                             mp.order = o
-            with tracer.span("search.oracle", segment=seg) as sp:
+            with tracer.span("search.oracle", segment=seg,
+                             path=rec.oracle_path) as sp:
                 improved = rec.improved
-                for ms in rounded_pop[:n_real]:
-                    rec.record(ms)
+                rec.record_batch(*stack_population(rounded_pop[:n_real]))
                 sp.set(candidates=n_real, improved=rec.improved - improved)
             # Continue GD from the rounded points, fresh momentum.
             theta = jnp.asarray(
@@ -1111,8 +1168,7 @@ def _dosa_search_fused(workload: Workload, cfg: SearchConfig,
                 spec=cspec, pe_cap=int(_pe_cap(cfg, cspec)), mode=mode)
         else:
             chunk = starts[lo:lo + population]
-            for mappings in chunk:
-                rec.record(mappings)
+            rec.record_batch(*stack_population(chunk))
             # Satellite fix: pad the ragged final chunk to `population`
             # with replicas of its last member — per-member ops make
             # padding inert, one program shape serves every chunk.
@@ -1142,12 +1198,11 @@ def _dosa_search_fused(workload: Workload, cfg: SearchConfig,
             f_seg = np.asarray(f_seg, dtype=float)  # (S, P, L, 2, nl, 7)
             o_seg = np.asarray(o_seg)               # (S, P, L, n_levels)
         for s, n_steps in enumerate(seg_lens):
-            with tracer.span("search.oracle", segment=s, chunk=lo) as sp:
+            with tracer.span("search.oracle", segment=s, chunk=lo,
+                             path=rec.oracle_path) as sp:
                 rec.count(n_steps * n_real)  # one sample per GD step
                 improved = rec.improved
-                for p in range(n_real):
-                    rec.record(
-                        unstack_mappings(f_seg[s, p], o_seg[s, p]))
+                rec.record_batch(f_seg[s, :n_real], o_seg[s, :n_real])
                 sp.set(candidates=n_real, improved=rec.improved - improved)
 
     return rec.finish()

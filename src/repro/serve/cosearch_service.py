@@ -89,7 +89,8 @@ from ..core.problem import Workload
 from ..core.search import (SearchConfig, _Recorder, _generate_start_point,
                            _segment_lengths, engine_cache_stats,
                            make_fused_runner, orders_from_population,
-                           shard_population, theta_from_population)
+                           shard_population, stack_population,
+                           theta_from_population)
 from ..launch.mesh import auto_pop_shards
 from ..core.fleet import fleet_engine_cache_stats
 from ..obs import telemetry as _obs
@@ -272,8 +273,7 @@ class _BatchTask:
                     rec.best.start_edps.append(edp0)
                     starts.append(mappings)
                 tries += rec.evals
-                for mappings in starts:
-                    rec.record(mappings)
+                rec.record_batch(*stack_population(starts))
                 thetas.append(theta_from_population(starts,
                                                     self.cspec.free_mask))
                 orders.append(orders_from_population(starts))
@@ -465,14 +465,14 @@ class _BatchTask:
             f_seg = np.asarray(f_seg, dtype=float)[0]  # (P_pad, L, 2, nl, 7)
             o_seg = np.asarray(o_seg)[0]               # (P_pad, L, n_levels)
 
-        with tracer.span("search.oracle", candidates=p_real) as sp:
+        # Requests batch only with equal `fixed_hw`, `fix_pe_only` and
+        # `latency_model` (`_batch_key`), so a task replays on one path.
+        with tracer.span("search.oracle", candidates=p_real,
+                         path=self.recs[0].oracle_path) as sp:
             improved = sum(rec.improved for rec in self.recs)
-            rounded = [unstack_mappings(f_seg[p], o_seg[p])
-                       for p in range(p_real)]
             for rec, (a, b) in zip(self.recs, self.spans):
                 rec.count(n_steps * (b - a))
-                for p in range(a, b):
-                    rec.record(rounded[p])
+                rec.record_batch(f_seg[a:b], o_seg[a:b])
             sp.set(improved=sum(rec.improved for rec in self.recs)
                    - improved)
         # The rounded population IS the next segment's start state: the
@@ -480,6 +480,8 @@ class _BatchTask:
         # segment, so the host rebuild is bit-identical to the device
         # carry (the PR-4 read-back guarantee).
         with tracer.span("task.rebuild"):
+            rounded = [unstack_mappings(f_seg[p], o_seg[p])
+                       for p in range(p_real)]
             self.theta = theta_from_population(rounded,
                                                self.cspec.free_mask
                                                ).astype(np.float32)
