@@ -29,6 +29,17 @@ class ArchConfig:
     experts_per_token: int = 0
     moe_layer_period: int = 1      # MoE every k-th layer (jamba: 2)
     capacity_factor: float = 1.25
+    n_shared_experts: int = 0      # always-on experts, one MLP of
+    #                                n_shared_experts * d_ff
+    first_k_dense: int = 0         # leading layers with a dense FFN
+    dense_d_ff: int = 0            # dense FFN width (0 = d_ff)
+
+    # --- multi-head latent attention (all 0 = none) ---
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- attention details ---
     activation: str = "swiglu"     # swiglu | geglu | relu2 | gelu
@@ -65,6 +76,9 @@ class ArchConfig:
             assert self.n_experts == 0
         if self.family == "ssm":
             assert self.ssm_state > 0
+        mla = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+               self.qk_rope_head_dim, self.v_head_dim)
+        assert all(mla) or not any(mla), mla
 
     @property
     def q_dim(self) -> int:
@@ -73,6 +87,20 @@ class ArchConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """MLA query/key width per head: the no-RoPE part plus the
+        RoPE part."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def dense_width(self) -> int:
+        return self.dense_d_ff or self.d_ff
 
     @property
     def d_inner(self) -> int:
@@ -92,8 +120,22 @@ class ArchConfig:
         return True
 
     def is_moe_layer(self, i: int) -> bool:
-        return self.n_experts > 0 and (i % self.moe_layer_period
-                                       == self.moe_layer_period - 1)
+        """Layer i's FFN is routed: every `moe_layer_period`-th layer
+        past the `first_k_dense` leading dense ones."""
+        return (self.n_experts > 0 and i >= self.first_k_dense
+                and i % self.moe_layer_period == self.moe_layer_period - 1)
+
+    def _attn_params(self) -> int:
+        if not self.is_mla:
+            return (self.d_model * (self.q_dim + 2 * self.kv_dim)
+                    + self.q_dim * self.d_model)
+        h, d = self.n_heads, self.d_model
+        p = d * self.q_lora_rank                                  # q_a
+        p += self.q_lora_rank * h * self.qk_head_dim              # q_b
+        p += d * (self.kv_lora_rank + self.qk_rope_head_dim)     # kv_a
+        p += self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                      + self.v_head_dim)         # kv_b
+        return p + h * self.v_head_dim * d                       # o
 
     def is_cross_attn_layer(self, i: int) -> bool:
         return (self.cross_attn_period > 0
@@ -109,21 +151,22 @@ class ArchConfig:
                 p += di * self.d_model                       # out_proj
                 p += 3 * nh                                  # A, D, dt_bias
             elif self.is_attn_layer(i):
-                p += self.d_model * (self.q_dim + 2 * self.kv_dim)
-                p += self.q_dim * self.d_model
+                p += self._attn_params()
             if self.is_cross_attn_layer(i):
                 p += self.d_model * (self.q_dim + 2 * self.kv_dim)
                 p += self.q_dim * self.d_model
             n_ff_mats = 3 if self.activation in ("swiglu", "geglu") else 2
             if self.is_moe_layer(i):
-                p += (self.n_experts * n_ff_mats * self.d_model * self.d_ff
+                p += ((self.n_experts + self.n_shared_experts) * n_ff_mats
+                      * self.d_model * self.d_ff
                       + self.d_model * self.n_experts)
             elif self.family not in ("ssm",):
-                p += n_ff_mats * self.d_model * self.d_ff
+                p += n_ff_mats * self.d_model * self.dense_width
         return p
 
     def n_active_params(self) -> int:
-        """Active parameters per token (MoE: only routed experts)."""
+        """Active parameters per token (MoE: the routed experts a token
+        takes and every shared one)."""
         if self.n_experts == 0:
             return self.n_params()
         p = self.n_params()

@@ -1137,7 +1137,8 @@ def _dosa_search_fused(workload: Workload, cfg: SearchConfig,
     starts = []
     if not device_seeded:
         with _obs.get_tracer().span("search.starts",
-                                    n=cfg.n_start_points) as sp:
+                                    n=cfg.n_start_points,
+                                    layers=len(workload.layers)) as sp:
             rng = np.random.default_rng(cfg.seed)
             best_start_edp = float("inf")
             for _ in range(cfg.n_start_points):

@@ -242,7 +242,7 @@ def attention_decode(params, cfg: ArchConfig, x, cache_k, cache_v,
 def mlp_init(key, cfg: ArchConfig):
     pdt = dtype_of(cfg.param_dtype)
     ks = jax.random.split(key, 3)
-    d, f = cfg.d_model, cfg.d_ff
+    d, f = cfg.d_model, cfg.dense_width
     gated = cfg.activation in ("swiglu", "geglu")
     params = {"w_up": dense_init(ks[0], (d, f), pdt),
               "w_down": dense_init(ks[1], (f, d), pdt,

@@ -50,7 +50,10 @@ def period_layout(cfg: ArchConfig) -> list[SlotSpec]:
     slots = []
     for i in range(period):
         kind = "attn" if cfg.is_attn_layer(i) else "ssm"
-        slots.append(SlotSpec(kind=kind, moe=cfg.is_moe_layer(i),
+        # the stack builds no leading dense layer: a slot's FFN follows
+        # the layers past them
+        moe = cfg.is_moe_layer(i + period * cfg.first_k_dense)
+        slots.append(SlotSpec(kind=kind, moe=moe,
                               cross=cfg.is_cross_attn_layer(i)))
     return slots
 

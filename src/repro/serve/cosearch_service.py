@@ -260,8 +260,8 @@ class _BatchTask:
         request, so accounting matches a direct run member-for-member."""
         self._fresh_recorders()
         thetas, orders = [], []
-        with self.tracer.span("search.starts",
-                              n=self.spans[-1][1]) as sp:
+        with self.tracer.span("search.starts", n=self.spans[-1][1],
+                              layers=len(self.workload.layers)) as sp:
             tries = 0       # oracle-checked start tries
             for req, rec in zip(self.requests, self.recs):
                 rcfg = req.config
@@ -646,7 +646,8 @@ class CoSearchService:
 
     def __init__(self, cfg: ServiceConfig | None = None):
         self.cfg = ServiceConfig() if cfg is None else cfg
-        self._pending: list[SearchRequest] = []
+        # (request, its workload as searched: bucketed where configured)
+        self._pending: list[tuple[SearchRequest, Workload]] = []
         self._tasks: list = []
         self._events: dict[str, list[ProgressEvent]] = {}
         self._outcomes: dict[str, SearchOutcome] = {}
@@ -700,6 +701,10 @@ class CoSearchService:
         self._c_fault_events = m.counter(
             "serve_fault_events_total", "fault-path span events, by "
             "kind", ("event",))
+        self._c_macs = m.counter(
+            "serve_request_macs_total", "MACs of the accepted requests: "
+            "as sent, and as searched after bucketing (searched / sent "
+            "- 1: the padding the live mix pays)", ("kind",))
         self._h_request = m.histogram(
             "serve_request_seconds", "submit-to-finalize latency")
         # request-lifecycle span bookkeeping (rid -> span ids)
@@ -747,15 +752,21 @@ class CoSearchService:
         if req.deadline_s is not None:
             self._deadlines[req.request_id] = faults.Deadline(
                 self.cfg.clock_fn, req.deadline_s)
-        self._pending.append(req)
+        wl = (bucket_workload(req.workload) if self.cfg.bucket_workloads
+              else req.workload)
+        self._pending.append((req, wl))
         self._events.setdefault(req.request_id, [])
         # request lifecycle trace: root span (open until finalize) with
         # a queue_wait child that closes at batch join
         self._c_submitted.inc()
         rid = req.request_id
+        macs, macs_searched = req.workload.total_macs, wl.total_macs
+        self._c_macs.inc(macs, kind="sent")
+        self._c_macs.inc(macs_searched, kind="searched")
         root = self.tracer.start_span(
             "request", request_id=rid,
             workload=req.workload.name, priority=req.priority,
+            macs=macs, macs_searched=macs_searched,
             **(trace_attrs or {}))
         self.tracer.add_event(root, "submitted")
         self._root_span[rid] = root
@@ -767,13 +778,9 @@ class CoSearchService:
     def _rid(self, request_id: str) -> str:
         return self._aliases.get(request_id, request_id)
 
-    def _canon_workload(self, req: SearchRequest) -> Workload:
-        return (bucket_workload(req.workload) if self.cfg.bucket_workloads
-                else req.workload)
-
-    def _batch_key(self, req: SearchRequest) -> tuple:
+    @staticmethod
+    def _batch_key(req: SearchRequest, wl: Workload) -> tuple:
         cfg = req.config
-        wl = self._canon_workload(req)
         traced = tuple(getattr(cfg, f) for f in _TRACED_CFG_FIELDS)
         extra = (cfg.fixed_hw, cfg.fix_pe_only, cfg.reject_factor,
                  cfg.max_reject_tries, cfg.latency_model,
@@ -815,12 +822,12 @@ class CoSearchService:
                                       batch_size=len(task.requests))
 
     def _form_batches(self) -> None:
-        groups: dict[tuple, list[SearchRequest]] = {}
-        for req in self._pending:
-            groups.setdefault(self._batch_key(req), []).append(req)
+        groups: dict[tuple, tuple[Workload, list[SearchRequest]]] = {}
+        for req, wl in self._pending:
+            groups.setdefault(self._batch_key(req, wl), (wl, []))[1].append(
+                req)
         self._pending = []
-        for key, reqs in groups.items():
-            wl = self._canon_workload(reqs[0])
+        for wl, reqs in groups.values():
             for lo in range(0, len(reqs), self.cfg.batch_max):
                 chunk = reqs[lo:lo + self.cfg.batch_max]
                 specs = {_spec_of(r.config) for r in chunk}

@@ -8,6 +8,21 @@ batch x heads x layers repeat counts (their "weights" — the K/V blocks —
 are not shared, so repeats, not the conv batch dim, model them).  MoE
 expert GEMMs count only routed (active) tokens, matching the
 6*N_active*D FLOP accounting used in the roofline analysis.
+
+Multi-head latent attention (DeepSeek-V2 Sec. 2.1) lowers in two forms.
+Prefill and train run it un-absorbed: the latent is up-projected
+(`kv_b`) and each head attends at qk width `qk_head_dim`, v width
+`v_head_dim`.  Decode runs it absorbed, as served: W_UK folds into the
+query and W_UV into the output, so every head of a sequence reads the
+one (kv_lora_rank + qk_rope_head_dim)-wide latent cache, and in score
+and context the heads are the GEMM's rows.
+
+`extract(..., ep_chips=n)` lowers one chip of an n-chip expert-parallel
+group: it holds n_experts / n experts of each MoE layer, and each of
+them takes the routed tokens of all n chips' batches (mean load).
+`extract(..., depth=k)` lowers a pipeline's first stage: the first k
+layers and no LM head.
+`workloads.lm_reference` computes the same GEMMs in plain JAX.
 """
 from __future__ import annotations
 
@@ -43,30 +58,94 @@ def _attn_layers(cfg: ArchConfig, tokens: int, seq: int, batch: int,
     return out
 
 
+def _mla_layers(cfg: ArchConfig, tokens: int, seq: int, batch: int,
+                mode: str, n_attn: int) -> list[Layer]:
+    """GEMMs of `n_attn` multi-head latent attention layers: absorbed
+    for decode, un-absorbed for prefill and train."""
+    if n_attn == 0:
+        return []
+    h, d = cfg.n_heads, cfg.d_model
+    lat = cfg.kv_lora_rank
+    m = tokens if mode != "decode" else batch
+    out = [
+        Layer.matmul(m, cfg.q_lora_rank, d, repeat=n_attn, name="q_a"),
+        Layer.matmul(m, h * cfg.qk_head_dim, cfg.q_lora_rank,
+                     repeat=n_attn, name="q_b"),
+        Layer.matmul(m, lat + cfg.qk_rope_head_dim, d, repeat=n_attn,
+                     name="kv_a"),
+    ]
+    if mode == "decode":
+        per_head = n_attn * h
+        per_seq = n_attn * batch
+        out += [
+            # q_nope @ W_UK, one (B x nope) @ (nope x lat) per head
+            Layer.matmul(batch, lat, cfg.qk_nope_head_dim,
+                         repeat=per_head, name="q_absorb"),
+            # per sequence: (heads x lat+rope) @ (lat+rope x L)
+            Layer.matmul(h, seq, lat + cfg.qk_rope_head_dim,
+                         repeat=per_seq, name="score"),
+            # per sequence: (heads x L) @ (L x lat)
+            Layer.matmul(h, lat, seq, repeat=per_seq, name="context"),
+            # context latent @ W_UV, per head
+            Layer.matmul(batch, cfg.v_head_dim, lat, repeat=per_head,
+                         name="v_absorb"),
+        ]
+    else:
+        reps = n_attn * h * batch
+        out += [
+            Layer.matmul(m, h * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+                         lat, repeat=n_attn, name="kv_b"),
+            Layer.matmul(seq, seq, cfg.qk_head_dim, repeat=reps,
+                         name="score"),
+            Layer.matmul(seq, cfg.v_head_dim, seq, repeat=reps,
+                         name="context"),
+        ]
+    out.append(Layer.matmul(m, d, h * cfg.v_head_dim, repeat=n_attn,
+                            name="attn_out"))
+    return out
+
+
 def _ffn_layers(cfg: ArchConfig, tokens: int, mode: str, batch: int,
-                n_dense: int, n_moe: int) -> list[Layer]:
+                n_dense: int, n_moe: int, ep_chips: int) -> list[Layer]:
+    """Dense FFN layers, then MoE layers as one chip of `ep_chips`
+    holds them: the full router, its share of the routed experts, each
+    on the routed tokens of every chip's batch, and the shared
+    experts."""
     m = tokens if mode != "decode" else batch
     n_mats_up = 2 if cfg.activation in ("swiglu", "geglu") else 1
     out = []
     if n_dense:
         out += [
-            Layer.matmul(m, cfg.d_ff, cfg.d_model,
+            Layer.matmul(m, cfg.dense_width, cfg.d_model,
                          repeat=n_dense * n_mats_up, name="ffn_up"),
-            Layer.matmul(m, cfg.d_model, cfg.d_ff, repeat=n_dense,
+            Layer.matmul(m, cfg.d_model, cfg.dense_width, repeat=n_dense,
                          name="ffn_down"),
         ]
     if n_moe:
+        if cfg.n_experts % ep_chips:
+            raise ValueError(f"{cfg.n_experts} experts do not split "
+                             f"over {ep_chips} chips")
+        held = cfg.n_experts // ep_chips
         out.append(Layer.matmul(m, cfg.n_experts, cfg.d_model,
                                 repeat=n_moe, name="router"))
         # Routed tokens per expert (active compute only).
-        m_exp = max(m * cfg.experts_per_token // cfg.n_experts, 1)
+        m_exp = max(m * ep_chips * cfg.experts_per_token
+                    // cfg.n_experts, 1)
         out += [
             Layer.matmul(m_exp, cfg.d_ff, cfg.d_model,
-                         repeat=n_moe * cfg.n_experts * n_mats_up,
+                         repeat=n_moe * held * n_mats_up,
                          name="expert_up"),
             Layer.matmul(m_exp, cfg.d_model, cfg.d_ff,
-                         repeat=n_moe * cfg.n_experts, name="expert_down"),
+                         repeat=n_moe * held, name="expert_down"),
         ]
+        if cfg.n_shared_experts:
+            width = cfg.n_shared_experts * cfg.d_ff
+            out += [
+                Layer.matmul(m, width, cfg.d_model,
+                             repeat=n_moe * n_mats_up, name="shared_up"),
+                Layer.matmul(m, cfg.d_model, width, repeat=n_moe,
+                             name="shared_down"),
+            ]
     return out
 
 
@@ -105,30 +184,42 @@ def _ssm_layers(cfg: ArchConfig, tokens: int, mode: str, batch: int,
     return out
 
 
-def extract(cfg: ArchConfig, shape: ShapeConfig) -> Workload:
+def extract(cfg: ArchConfig, shape: ShapeConfig, *, ep_chips: int = 1,
+            depth: int | None = None) -> Workload:
+    """The GEMMs one chip runs for `shape`: all of the model's layers,
+    or the first `depth` of them as a pipeline's first stage holds them
+    (the LM head sits on the last stage, so a stage short of the last
+    layer has none), with the routed experts split over `ep_chips`
+    chips."""
     ok, why = shape_applicable(cfg, shape)
     if not ok:
         raise ValueError(f"{cfg.name} x {shape.name} skipped: {why}")
     seq, batch, mode = shape.seq_len, shape.global_batch, shape.mode
     tokens = seq * batch
+    n_layers = cfg.n_layers if depth is None else depth
+    idx = range(n_layers)
 
-    n_attn = sum(cfg.is_attn_layer(i) for i in range(cfg.n_layers))
-    n_ssm = cfg.n_layers - n_attn if cfg.family in ("ssm", "hybrid") else 0
-    n_moe = sum(cfg.is_moe_layer(i) for i in range(cfg.n_layers))
-    n_cross = sum(cfg.is_cross_attn_layer(i) for i in range(cfg.n_layers))
-    n_dense_ffn = (cfg.n_layers - n_moe) if cfg.family != "ssm" else 0
+    n_attn = sum(cfg.is_attn_layer(i) for i in idx)
+    n_ssm = n_layers - n_attn if cfg.family in ("ssm", "hybrid") else 0
+    n_moe = sum(cfg.is_moe_layer(i) for i in idx)
+    n_cross = sum(cfg.is_cross_attn_layer(i) for i in idx)
+    n_dense_ffn = (n_layers - n_moe) if cfg.family != "ssm" else 0
 
     layers: list[Layer] = []
-    layers += _attn_layers(cfg, tokens, seq, batch, mode, n_attn)
+    if cfg.is_mla:
+        layers += _mla_layers(cfg, tokens, seq, batch, mode, n_attn)
+    else:
+        layers += _attn_layers(cfg, tokens, seq, batch, mode, n_attn)
     if n_cross:
         layers += _attn_layers(cfg, tokens, seq, batch, mode, n_cross,
                                kv_len=cfg.n_image_tokens, tag="x")
-    layers += _ffn_layers(cfg, tokens, mode, batch, n_dense_ffn, n_moe)
+    layers += _ffn_layers(cfg, tokens, mode, batch, n_dense_ffn, n_moe,
+                          ep_chips)
     layers += _ssm_layers(cfg, tokens, mode, batch, n_ssm)
-    # LM head (decode emits one token per sequence).
-    m_head = tokens if mode == "train" else (batch if mode == "decode"
-                                             else batch)
-    layers.append(Layer.matmul(m_head, cfg.vocab_size, cfg.d_model,
-                               name="lm_head"))
+    if n_layers == cfg.n_layers:
+        # LM head (decode emits one token per sequence).
+        m_head = tokens if mode == "train" else batch
+        layers.append(Layer.matmul(m_head, cfg.vocab_size, cfg.d_model,
+                                   name="lm_head"))
     wl = dedupe_layers(layers)
     return Workload(layers=wl.layers, name=f"{cfg.name}:{shape.name}")

@@ -1,6 +1,7 @@
 """Compile the chip's main-path programs for a TPU v5e that is described,
 not attached: the fused segment program of both `chip_smoke.py`
-requests, the DOSA-tuned Pallas matmul and the GQA flash attention.
+requests and of the Kimi-K2 decode step, the DOSA-tuned Pallas matmul
+and the GQA flash attention.
 The TPU compiler refuses here what the chip would refuse (VMEM
 overflow, unaligned tiles, programs that do not fit), at no chip time.
 
@@ -16,8 +17,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.configs.base import SHAPES
-from repro.core.archspec import GEMMINI_SPEC, TPU_V5E_SPEC, resolve_spec
+from repro.configs.base import SHAPES, ShapeConfig
+from repro.core.archspec import (GEMMINI_SPEC, TPU_V5E_SPEC, bucket_workload,
+                                 resolve_spec)
 from repro.core.autotune import default_blocks
 from repro.core.search import SearchConfig, make_fused_runner
 from repro.kernels.flash_attention.ops import gqa_flash_attention
@@ -65,12 +67,20 @@ def _sds(shape, dtype, sharding):
 def _smoke_request(name):
     if name == "resnet50-gemmini":
         return resnet50(), GEMMINI_SPEC
+    if name == "kimi_k2-decode32k-ep48-tpu_v5e":
+        # the whole decode step the Kimi-K2 benchmark cell serves, as
+        # the service searches it (bucketed)
+        return bucket_workload(extract(
+            get_config("kimi_k2_1t"),
+            ShapeConfig("decode32k", 32768, 24, "decode"), ep_chips=48,
+            depth=5)), TPU_V5E_SPEC
     return extract(get_config("qwen3_0_6b"), SHAPES["decode_32k"]), \
         TPU_V5E_SPEC
 
 
 @pytest.mark.parametrize("name", ["resnet50-gemmini",
-                                  "qwen3_0_6b-decode_32k-tpu_v5e"])
+                                  "qwen3_0_6b-decode_32k-tpu_v5e",
+                                  "kimi_k2-decode32k-ep48-tpu_v5e"])
 def test_fused_segment_program_compiles(one_chip, name):
     wl, spec = _smoke_request(name)
     cfg = dataclasses.replace(SMOKE_CFG, spec=spec)

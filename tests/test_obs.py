@@ -403,6 +403,31 @@ def test_direct_fused_oracle_spans_count_candidates():
     assert starts.attrs["n"] == 3
     assert 3 <= starts.attrs["tries"] <= 3 * cfg.max_reject_tries
     assert res.n_evals == starts.attrs["tries"] + 3 + 3 * (6 + 3)
+    assert starts.attrs["layers"] == 1
+
+
+def test_request_span_and_counter_carry_macs_sent_and_searched():
+    """Each accepted request's root span carries its MACs as sent and
+    as searched after bucketing, the service's counter sums both, and
+    the served `search.starts` span names the layers it mapped."""
+    wl = Workload(layers=(Layer.matmul(24, 576, 7168, name="kv_a"),
+                          Layer.matmul(64, 32768, 576, repeat=3,
+                                       name="score")), name="step")
+    sent = 24 * 576 * 7168 + 3 * 64 * 32768 * 576
+    searched = 24 * 768 * 8192 + 3 * 64 * 32768 * 768   # ladder pads
+    svc = CoSearchService(ServiceConfig(bucket_workloads=True))
+    rid = svc.submit(SearchRequest(workload=wl, config=_cfg(7)))
+    svc.submit(SearchRequest(workload=wl, config=_cfg(7)))   # duplicate
+    assert svc.drain()[rid].status == "ok"
+    (root,) = svc.tracer.spans_named("request")
+    assert (root.attrs["macs"], root.attrs["macs_searched"]) == (
+        sent, searched)
+    c = svc.metrics.counter("serve_request_macs_total", labelnames=("kind",))
+    assert (c.value(kind="sent"), c.value(kind="searched")) == (sent,
+                                                                searched)
+    (starts,) = svc.tracer.spans_named("search.starts")
+    assert starts.attrs["layers"] == 2
+    assert "serve_request_macs_total" in svc.metrics_text()
 
 
 def test_trace_records_retry_and_backoff_events():
